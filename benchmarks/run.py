@@ -49,7 +49,6 @@ MODULES = (
     "benchmarks.fig9_12_competitors",
     "benchmarks.fig14_preprocessing",
     "benchmarks.micro_merge_pool",
-    "benchmarks.micro_fused_query",
 )
 
 # suite name -> "module" (entry point `run`) or "module:function"

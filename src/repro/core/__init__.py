@@ -37,6 +37,7 @@ from repro.core.suco import (
     STREAMING_MIN_N,
     EnginePolicy,
     EngineStats,
+    QueryStats,
     SuCoConfig,
     SuCoEngine,
     SuCoIndex,
@@ -77,6 +78,7 @@ __all__ = [
     "INDEX_ARTIFACT_VERSION",
     "EnginePolicy",
     "EngineStats",
+    "QueryStats",
     "SuCoConfig",
     "SuCoEngine",
     "SuCoIndex",

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.distances import pairwise_sqdist
+from repro.spans import scope
 
 __all__ = [
     "KMeansResult",
@@ -381,8 +382,6 @@ def _kmeans_core(
 
     # algo == "lloyd"
     chunked = block_n > 0
-    if chunked and not pallas:
-        blocks, valid = block_batched(xs, block_n)
 
     def lloyd_body(c, _):
         if not chunked:
@@ -396,9 +395,15 @@ def _kmeans_core(
         new = jnp.where(counts[..., None] > 0, new, c.astype(jnp.float32))
         return new.astype(c.dtype), inertia
 
-    centroids, inertias = jax.lax.scan(lloyd_body, c0, None, length=iters)
-    a, _, counts = _final_assign(xs, centroids, block_n=block_n, pallas=pallas,
-                                 need_inertia=False, pair_sqrt_k=pair_sqrt_k)
+    with scope("kmeans.lloyd"):
+        if chunked and not pallas:
+            blocks, valid = block_batched(xs, block_n)
+        centroids, inertias = jax.lax.scan(lloyd_body, c0, None, length=iters)
+    with scope("kmeans.assign"):
+        a, _, counts = _final_assign(
+            xs, centroids, block_n=block_n, pallas=pallas,
+            need_inertia=False, pair_sqrt_k=pair_sqrt_k,
+        )
     return KMeansResult(centroids, a, inertias[-1], counts)
 
 
@@ -519,5 +524,6 @@ def kmeans_batched(
     :func:`assign_scan`).
     """
     _check_args(algo, block_n, init)
-    c0 = _init_batched(key, xs, k, init, algo)
+    with scope("kmeans.init"):
+        c0 = _init_batched(key, xs, k, init, algo)
     return _kmeans_core(key, xs, c0, iters, algo, block_n, impl, pair_sqrt_k)

@@ -126,6 +126,7 @@ from repro.kernels.sc_score.ops import (
     sc_scores_cells,
     sc_scores_cells_prefilter_compact,
 )
+from repro.spans import scope, span
 
 __all__ = [
     "SuCoConfig",
@@ -146,6 +147,7 @@ __all__ = [
     "assign_points",
     "EnginePolicy",
     "EngineStats",
+    "QueryStats",
     "SuCoEngine",
     "batch_bucket",
     "autoscale_buckets",
@@ -480,9 +482,10 @@ def _build(
     block_n: int = 0,
 ):
     ns = spec.n_subspaces
-    xp = sub.permute(spec, x)
-    h1, h2 = sub.split_halves_padded(spec, xp)  # 2 x (Ns, n, h_max)
-    both = jnp.concatenate([h1, h2], axis=0)  # (2Ns, n, h_max)
+    with scope("halves"):
+        xp = sub.permute(spec, x)
+        h1, h2 = sub.split_halves_padded(spec, xp)  # 2 x (Ns, n, h_max)
+        both = jnp.concatenate([h1, h2], axis=0)  # (2Ns, n, h_max)
     # block_n=0 is the dense reference; >0 streams every K-means pass —
     # including the final assignment feeding cell_ids — in block_n chunks.
     # pair_sqrt_k fuses the IMI occupancy histogram into that final
@@ -510,40 +513,44 @@ def build_index(x: jax.Array, config: SuCoConfig, *, spec: sub.SubspaceSpec | No
     to fp summation order (boundary points can differ after many
     iterations).
     """
-    if spec is None:
-        spec = sub.contiguous_spec(x.shape[-1], config.n_subspaces)
-    mode = config.build_mode
-    if mode not in _BUILD_MODES:
-        raise ValueError(f"unknown build_mode {mode!r}, expected one of {_BUILD_MODES}")
-    if mode == "auto":
-        mode = "chunked" if x.shape[0] >= STREAMING_MIN_N else "dense"
-    if mode != "dense" and config.block_n < 0:
-        raise ValueError(
-            f"build_mode={mode!r} requires block_n >= 0 (0 = autotune), "
-            f"got {config.block_n}"
-        )
-    algo = "minibatch" if mode == "minibatch" else "lloyd"
-    if mode == "dense":
-        block_n = 0
-    elif config.block_n == 0:  # autotune from backend limits + data shape
-        block_n = autotune_build_block_n(
-            x.shape[0],
-            x.shape[-1],
+    with span("build.plan"):
+        if spec is None:
+            spec = sub.contiguous_spec(x.shape[-1], config.n_subspaces)
+        mode = config.build_mode
+        if mode not in _BUILD_MODES:
+            raise ValueError(
+                f"unknown build_mode {mode!r}, expected one of {_BUILD_MODES}"
+            )
+        if mode == "auto":
+            mode = "chunked" if x.shape[0] >= STREAMING_MIN_N else "dense"
+        if mode != "dense" and config.block_n < 0:
+            raise ValueError(
+                f"build_mode={mode!r} requires block_n >= 0 (0 = autotune), "
+                f"got {config.block_n}"
+            )
+        algo = "minibatch" if mode == "minibatch" else "lloyd"
+        if mode == "dense":
+            block_n = 0
+        elif config.block_n == 0:  # autotune from backend limits + data shape
+            block_n = autotune_build_block_n(
+                x.shape[0],
+                x.shape[-1],
+                sqrt_k=config.sqrt_k,
+                n_subspaces=config.n_subspaces,
+            )
+        else:
+            block_n = config.block_n
+    with span("build.dispatch"):
+        key = jax.random.key(config.seed)
+        c1, c2, cell_ids, counts = _build(
+            x,
+            key,
+            spec=spec,
             sqrt_k=config.sqrt_k,
-            n_subspaces=config.n_subspaces,
+            iters=config.kmeans_iters,
+            algo=algo,
+            block_n=block_n,
         )
-    else:
-        block_n = config.block_n
-    key = jax.random.key(config.seed)
-    c1, c2, cell_ids, counts = _build(
-        x,
-        key,
-        spec=spec,
-        sqrt_k=config.sqrt_k,
-        iters=config.kmeans_iters,
-        algo=algo,
-        block_n=block_n,
-    )
     return SuCoIndex(c1, c2, cell_ids, counts, spec=spec, sqrt_k=config.sqrt_k)
 
 
@@ -868,6 +875,34 @@ def suco_cell_ranks(
     return jax.vmap(per_sub)(d1, d2, index.cell_counts)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class QueryStats:
+    """Counters of one fused-query execution over its whole padded batch.
+
+    ``overflow_chunks``: chunks in which some row's survivors overflowed
+    ``survivor_cap``, so the scan took the exact fallback for the whole
+    chunk.  ``overflow_rows``: (row, chunk) pairs whose own survivors
+    overflowed — the part of that fallback a per-row one would still do.
+    ``chunks`` and ``rows`` are the scan's static extent.
+    """
+
+    overflow_chunks: jax.Array  # () int32
+    overflow_rows: jax.Array  # () int32
+    chunks: int = dataclasses.field(metadata=dict(static=True))
+    rows: int = dataclasses.field(metadata=dict(static=True))
+
+
+class EngineResult(NamedTuple):
+    """What the engine's executable returns: the answer and the fused
+    scan's :class:`QueryStats` (``None`` on the dense and streaming paths)."""
+
+    ids: jax.Array
+    dists: jax.Array
+    scores: jax.Array
+    stats: QueryStats | None = None
+
+
 def _pool_size(n: int, k: int, beta: float) -> int:
     """Candidate-pool size — the shared clamped form
     (:func:`repro.core.sc_linear.candidate_pool_size`)."""
@@ -960,12 +995,12 @@ def suco_query_streaming(
     return rerank_candidates(x, q, pool_i, pool_s, k, metric)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "alpha", "beta", "metric", "tiles", "score_impl", "merge_impl"
-    ),
+_FUSED_STATIC = (
+    "k", "alpha", "beta", "metric", "tiles", "score_impl", "merge_impl"
 )
+
+
+@functools.partial(jax.jit, static_argnames=_FUSED_STATIC)
 def suco_query_fused(
     x: jax.Array,
     index: SuCoIndex,
@@ -1026,6 +1061,27 @@ def suco_query_fused(
     (:func:`repro.core.tuning.autotune_tiles`); pass an explicit
     :class:`~repro.core.tuning.TileConfig` to pin them.
     """
+    return _fused_query(
+        x, index, q, k=k, alpha=alpha, beta=beta, metric=metric, tiles=tiles,
+        score_impl=score_impl, merge_impl=merge_impl,
+    )[0]
+
+
+@functools.partial(jax.jit, static_argnames=_FUSED_STATIC)
+def _fused_query(
+    x: jax.Array,
+    index: SuCoIndex,
+    q: jax.Array,
+    *,
+    k: int,
+    alpha: float,
+    beta: float,
+    metric: Metric,
+    tiles: TileConfig | None,
+    score_impl: str,
+    merge_impl: str,
+) -> tuple[QueryResult, QueryStats]:
+    """:func:`suco_query_fused` and the :class:`QueryStats` of its scan."""
     n, d = x.shape
     if k > n:
         raise ValueError(f"k={k} must be <= n={n}")
@@ -1039,7 +1095,8 @@ def suco_query_fused(
             itemsize=x.dtype.itemsize,
         )
     c = sub.collision_count(n, alpha)
-    ranks, cuts = suco_cell_ranks(index, q, c, metric)  # (Ns,m,K), (Ns,m)
+    with scope("activate"):
+        ranks, cuts = suco_cell_ranks(index, q, c, metric)  # (Ns,m,K), (Ns,m)
 
     bn = min(tiles.block_n, n)
     cap = min(tiles.survivor_cap, bn)
@@ -1068,10 +1125,11 @@ def suco_query_fused(
         blk, cells_b, keep_b = inp  # (), (Ns, bn), (bn,) | None
         thr = pool_s[:, -1]  # pool sorted desc -> last col is the minimum
         limit = jnp.minimum(n - blk * bn, bn)  # valid columns this chunk
-        s, surv_c, surv_s, total = sc_scores_cells_prefilter_compact(
-            ranks, cuts, cells_b, thr, limit, keep_b,
-            cap=cap, bm=tiles.bm, bn=tiles.bn, impl=score_impl,
-        )  # (m, bn), (m, cap), (m, cap), (m) — all int32, s pre-masked
+        with scope("score"):
+            s, surv_c, surv_s, total = sc_scores_cells_prefilter_compact(
+                ranks, cuts, cells_b, thr, limit, keep_b,
+                cap=cap, bm=tiles.bm, bn=tiles.bn, impl=score_impl,
+            )  # (m, bn), (m, cap), (m, cap), (m) — all int32, s pre-masked
         gids = blk * bn + cols
         col_ok = cols < limit
         if keep_b is not None:
@@ -1089,23 +1147,28 @@ def suco_query_fused(
             # (>= 0) score — the second clause is vacuous for immutable
             # indexes (survivors beat thr >= -1) and masks the Pallas
             # path's post-hoc tombstoned survivors under mutation.
-            live = jnp.logical_and(
-                slot[None, :] < total[:, None], surv_s >= 0
-            )
-            surv_i = jnp.where(live, blk * bn + surv_c, int_max)
-            surv_sm = jnp.where(live, surv_s, -1)
-            # survivors only ever touch O(cap) rows of x per chunk — the
-            # rows just scored, fetched by global id (the op clips the
-            # int_max sentinels; their distances are masked to +inf).
-            # impl="jnp" pins the fp reduction to rerank_candidates'
-            # rowwise contract on every backend; the Pallas gather kernel
-            # stays opt-in until a real-TPU run proves it ulp-identical.
-            dists = gather_rerank_block(surv_i, x, q, metric=metric, impl="jnp")
-            dists = jnp.where(live, dists, inf)
-            return merge_topk_pool_with_dists(
-                pool_s, pool_d, pool_i, surv_sm, dists, surv_i,
-                impl=merge_impl, smax=index.spec.n_subspaces,
-            )
+            with scope("rerank"):
+                live = jnp.logical_and(
+                    slot[None, :] < total[:, None], surv_s >= 0
+                )
+                surv_i = jnp.where(live, blk * bn + surv_c, int_max)
+                surv_sm = jnp.where(live, surv_s, -1)
+                # survivors only ever touch O(cap) rows of x per chunk —
+                # the rows just scored, fetched by global id (the op clips
+                # the int_max sentinels; their distances are masked to
+                # +inf).  impl="jnp" pins the fp reduction to
+                # rerank_candidates' rowwise contract on every backend; the
+                # Pallas gather kernel stays opt-in until a real-TPU run
+                # proves it ulp-identical.
+                dists = gather_rerank_block(
+                    surv_i, x, q, metric=metric, impl="jnp"
+                )
+                dists = jnp.where(live, dists, inf)
+            with scope("merge"):
+                return merge_topk_pool_with_dists(
+                    pool_s, pool_d, pool_i, surv_sm, dists, surv_i,
+                    impl=merge_impl, smax=index.spec.n_subspaces,
+                )
 
         def full_merge(_):
             # Exact overflow fallback: the merged top-pool can absorb at
@@ -1116,43 +1179,56 @@ def suco_query_fused(
             # selection instead of an O(pool + bn) one, with distances for
             # `pool` rows instead of `bn`.
             c = min(pool, bn)
-            top_s, top_pos = jax.lax.top_k(s, c)
-            top_i = jnp.take_along_axis(ids_b, top_pos, axis=-1)
-            dists = gather_rerank_block(top_i, x, q, metric=metric, impl="jnp")
-            dists = jnp.where(top_i == int_max, inf, dists)
-            return merge_topk_pool_with_dists(
-                pool_s, pool_d, pool_i, top_s, dists, top_i,
-                impl=merge_impl, smax=index.spec.n_subspaces,
-            )
+            with scope("fallback"):
+                top_s, top_pos = jax.lax.top_k(s, c)
+                top_i = jnp.take_along_axis(ids_b, top_pos, axis=-1)
+                dists = gather_rerank_block(
+                    top_i, x, q, metric=metric, impl="jnp"
+                )
+                dists = jnp.where(top_i == int_max, inf, dists)
+                return merge_topk_pool_with_dists(
+                    pool_s, pool_d, pool_i, top_s, dists, top_i,
+                    impl=merge_impl, smax=index.spec.n_subspaces,
+                )
 
-        overflow = jnp.any(total > cap)
-        return jax.lax.cond(overflow, full_merge, pruned_merge, None), None
+        over = total > cap  # (m,) rows whose survivors overflow the cap
+        overflow = jnp.any(over)
+        merged = jax.lax.cond(overflow, full_merge, pruned_merge, None)
+        return merged, (overflow, jnp.sum(over, dtype=jnp.int32))
 
     init = (
         jnp.full((m, pool), -1, jnp.int32),
         jnp.full((m, pool), inf, dist_dtype),
         jnp.full((m, pool), int_max, jnp.int32),
     )
-    (pool_s, pool_d, pool_i), _ = jax.lax.scan(
+    (pool_s, pool_d, pool_i), (over_chunk, over_rows) = jax.lax.scan(
         step, init, (jnp.arange(n_blocks, dtype=jnp.int32), cells, keep_blocks)
     )
     # Final selection == rerank_candidates' top_k on the carried pool:
     # ascending distance, ties to the earlier (score desc, id asc) slot.
-    neg, pos = jax.lax.top_k(-pool_d, k)
-    return QueryResult(
-        jnp.take_along_axis(pool_i, pos, axis=-1).astype(jnp.int32),
-        -neg,
-        jnp.take_along_axis(pool_s, pos, axis=-1),
+    with scope("select"):
+        neg, pos = jax.lax.top_k(-pool_d, k)
+        res = QueryResult(
+            jnp.take_along_axis(pool_i, pos, axis=-1).astype(jnp.int32),
+            -neg,
+            jnp.take_along_axis(pool_s, pos, axis=-1),
+        )
+    stats = QueryStats(
+        overflow_chunks=jnp.sum(over_chunk, dtype=jnp.int32),
+        overflow_rows=jnp.sum(over_rows, dtype=jnp.int32),
+        chunks=n_blocks,
+        rows=m,
     )
+    return res, stats
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "alpha", "beta", "metric", "mode", "block_n", "score_impl",
-        "tiles", "merge_impl",
-    ),
+_QUERY_STATIC = (
+    "k", "alpha", "beta", "metric", "mode", "block_n", "score_impl", "tiles",
+    "merge_impl",
 )
+
+
+@functools.partial(jax.jit, static_argnames=_QUERY_STATIC)
 def suco_query(
     x: jax.Array,
     index: SuCoIndex,
@@ -1184,11 +1260,36 @@ def suco_query(
     bit-identical, and "auto" resolves to the counting-select over the
     integer ``0..Ns`` score range.  The dense path ignores it.
     """
+    res = _query(
+        x, index, q, k=k, alpha=alpha, beta=beta, metric=metric, mode=mode,
+        block_n=block_n, score_impl=score_impl, tiles=tiles,
+        merge_impl=merge_impl,
+    )
+    return QueryResult(res.ids, res.dists, res.scores)
+
+
+@functools.partial(jax.jit, static_argnames=_QUERY_STATIC)
+def _query(
+    x: jax.Array,
+    index: SuCoIndex,
+    q: jax.Array,
+    *,
+    k: int,
+    alpha: float,
+    beta: float,
+    metric: Metric,
+    mode: str,
+    block_n: int,
+    score_impl: str,
+    tiles: TileConfig | None,
+    merge_impl: str,
+) -> EngineResult:
+    """:func:`suco_query` and, on the fused path, its :class:`QueryStats`."""
     n = x.shape[0]
     if mode not in ("auto", "dense", "streaming", "fused"):
         raise ValueError(f"unknown mode {mode!r}")
     if mode == "fused" or (mode == "auto" and n >= STREAMING_MIN_N):
-        return suco_query_fused(
+        res, stats = _fused_query(
             x,
             index,
             q,
@@ -1200,8 +1301,9 @@ def suco_query(
             score_impl=score_impl,
             merge_impl=merge_impl,
         )
+        return EngineResult(*res, stats)
     if mode == "streaming":
-        return suco_query_streaming(
+        return EngineResult(*suco_query_streaming(
             x,
             index,
             q,
@@ -1212,7 +1314,7 @@ def suco_query(
             block_n=block_n,
             score_impl=score_impl,
             merge_impl=merge_impl,
-        )
+        ))
     c = sub.collision_count(n, alpha)
     scores = suco_scores(index, q, c, metric)  # (m, n)
     if index.tombstone is not None:
@@ -1221,7 +1323,7 @@ def suco_query(
         # so a deleted id can neither crowd out pool slots nor be returned.
         scores = jnp.where(index.tombstone[None, :], -1, scores)
     n_candidates = candidate_pool_size(n, k, beta)
-    return rerank(x, q, scores, k, n_candidates, metric)
+    return EngineResult(*rerank(x, q, scores, k, n_candidates, metric))
 
 
 # --------------------------------------------------------------------------
@@ -1737,11 +1839,14 @@ class SuCoEngine:
 
     # ---- query -----------------------------------------------------------
 
-    def _raw_query(self, x: jax.Array, index: SuCoIndex, q: jax.Array, *, k: int):
-        # one implementation, two entry points: routing through suco_query
-        # keeps the wrapper's bit-identical contract true by construction
+    def _raw_query(
+        self, x: jax.Array, index: SuCoIndex, q: jax.Array, *, k: int
+    ) -> EngineResult:
+        # one implementation, two entry points: suco_query runs the same
+        # dispatcher, which keeps the wrapper's bit-identical contract
+        # true by construction
         p = self.policy
-        return suco_query(
+        return _query(
             x, index, q, k=k, alpha=p.alpha, beta=p.beta, metric=p.metric,
             mode=self._mode, block_n=p.block_n, score_impl=p.score_impl,
             tiles=p.tiles, merge_impl=p.merge_impl,
@@ -1772,6 +1877,14 @@ class SuCoEngine:
         executable, slices the padding back off.  Results are bit-identical
         to ``suco_query`` on the unpadded batch.
         """
+        return self.query_with_stats(q, k)[0]
+
+    def query_with_stats(
+        self, q: jax.Array, k: int
+    ) -> tuple[QueryResult, QueryStats | None]:
+        """:meth:`query` and the executable's :class:`QueryStats`, counted
+        over the whole padded bucket; ``None`` off the fused path.  Both
+        stay on the device until the caller reads them."""
         q = jnp.asarray(q)
         single = q.ndim == 1
         if single:
@@ -1789,17 +1902,19 @@ class SuCoEngine:
         b = batch_bucket(m, self.policy.batch_buckets)
         if b != m:
             q = jnp.pad(q, ((0, b - m), (0, 0)))
-        res = self._jit(self.x, self.index, q, k=k)
+        out = self._jit(self.x, self.index, q, k=k)
         self._batches += 1
         self._queries += m
         self._padded += b - m
         self._buckets_seen.add((b, k))
         self.policy.observe((m,))  # feed the autoscaler's traffic histogram
+        # a wrapped _raw_query that rebuilds a plain QueryResult has no stats
+        stats = getattr(out, "stats", None)
         if single:
-            return QueryResult(res.ids[0], res.dists[0], res.scores[0])
+            return QueryResult(out.ids[0], out.dists[0], out.scores[0]), stats
         if b != m:
-            res = QueryResult(res.ids[:m], res.dists[:m], res.scores[:m])
-        return res
+            return QueryResult(out.ids[:m], out.dists[:m], out.scores[:m]), stats
+        return QueryResult(out.ids, out.dists, out.scores), stats
 
     def warmup(
         self,

@@ -64,11 +64,19 @@ import time
 from collections import deque
 from typing import Callable, Sequence
 
+import jax
 import numpy as np
 
 from repro.core import theory
 from repro.core.sc_linear import QueryResult
-from repro.core.suco import EnginePolicy, SuCoConfig, SuCoEngine, batch_bucket
+from repro.core.suco import (
+    EnginePolicy,
+    QueryStats,
+    SuCoConfig,
+    SuCoEngine,
+    batch_bucket,
+)
+from repro.spans import span
 
 __all__ = [
     "AnnRequest",
@@ -101,6 +109,7 @@ class AnnRequest:
     degrade_level: int = 0  # ladder level the answer was served at
     quality_bound: float | None = None  # Theorem-2 floor for that level
     retries: int = 0  # transient-dispatch-error retries spent
+    step: StepRecord | None = None  # the step whose batch answered it
 
     @property
     def done(self) -> bool:
@@ -146,6 +155,12 @@ class StepRecord:
     dispatch_s: float = 0.0  # host time to form/pad/enqueue the batch
     # (the synchronous server folds dispatch into step_s and leaves this 0)
     level: int = 0  # degradation-ladder level the step was served at
+    # The fused scan's counters over the padded batch (QueryStats); None
+    # where the executable reports none (dense, streaming, a proxy engine).
+    overflow_chunks: int | None = None  # chunks that took the fallback
+    overflow_rows: int | None = None  # (row, chunk) pairs over the cap
+    chunks: int | None = None  # chunks the scan ran
+    rows: int | None = None  # rows of the padded batch
 
 
 @dataclasses.dataclass
@@ -582,13 +597,15 @@ class AnnServer:
 
     # ---- fault isolation -------------------------------------------------
 
-    def _query_with_retry(self, engine: SuCoEngine, batch, q, k: int):
+    def _query_with_retry(
+        self, engine: SuCoEngine, batch, q, k: int
+    ) -> tuple[QueryResult, QueryStats | None]:
         """One batch dispatch, retried ``max_retries`` times with jittered
         backoff on transient (non-ValueError) failures."""
         attempts = self.max_retries + 1
         for attempt in range(attempts):
             try:
-                return engine.query(q, k=k)
+                return _query_with_stats(engine, q, k)
             except ValueError:
                 raise  # malformed input: retrying cannot help
             except Exception:
@@ -623,21 +640,16 @@ class AnnServer:
             return []
         level = self._serving_level(now)
         engine = self._engine_for(level)
-        batch, k = self._form_batch()
-
-        t0 = self.clock()
-        for r in batch:
-            r.t_start = t0
-        qs = [np.asarray(r.query) for r in batch]  # jaxlint: sync-ok — host payload
+        batch, k, t0, q = self._form()
+        counters: dict = {}
         try:
-            res = self._query_with_retry(engine, batch, np.stack(qs), k)
-            ids = np.asarray(res.ids)  # jaxlint: sync-ok — sync serving step
-            dists = np.asarray(res.dists)  # jaxlint: sync-ok
-            t1 = self.clock()
-            qb = self._quality_bound(level, k)
-            for i, r in enumerate(batch):
-                r.ids, r.dists, r.t_done = ids[i], dists[i], t1
-                r.degrade_level, r.quality_bound = level, qb
+            with span("serve.dispatch"):
+                res, stats = self._query_with_retry(engine, batch, q, k)
+            with span("serve.wait"):
+                jax.block_until_ready(res)  # jaxlint: sync-ok — sync serving step
+            with span("serve.deliver"):
+                t1 = self._deliver(batch, res, level, k)
+                counters = _counters(stats)
         except ValueError as e:
             # A malformed batch (should be impossible past submit-time
             # validation) completes-with-error without sinking the server.
@@ -648,18 +660,50 @@ class AnnServer:
             # Retries exhausted: isolate per request.
             self._isolate(engine, batch, k, level)
             t1 = self.clock()
-        self.completed.extend(batch)
-        self.steps.append(
-            StepRecord(
-                n_requests=len(batch),
-                k=k,
-                bucket=batch_bucket(len(batch), engine.policy.batch_buckets),
-                step_s=t1 - t0,
-                compile_count=self.executables,
-                level=level,
-            )
-        )
+        self._finish(batch, engine, k, level, t1 - t0, **counters)
         return batch
+
+    def _form(self) -> tuple[list[AnnRequest], int, float, np.ndarray]:
+        """``(batch, k, dispatch time, stacked queries)`` of the next
+        micro-batch; every request's ``t_start`` is the dispatch time."""
+        with span("serve.form"):
+            batch, k = self._form_batch()
+            t0 = self.clock()
+            for r in batch:
+                r.t_start = t0
+            q = np.stack([np.asarray(r.query) for r in batch])  # jaxlint: sync-ok — host payload
+        return batch, k, t0, q
+
+    def _deliver(self, batch, res: QueryResult, level: int, k: int) -> float:
+        """Copy a finished batch's answers to its requests; returns the
+        time they were delivered."""
+        ids = np.asarray(res.ids)  # jaxlint: sync-ok — after serve.wait
+        dists = np.asarray(res.dists)  # jaxlint: sync-ok
+        t1 = self.clock()
+        qb = self._quality_bound(level, k)
+        for i, r in enumerate(batch):
+            r.ids, r.dists, r.t_done = ids[i], dists[i], t1
+            r.degrade_level, r.quality_bound = level, qb
+        return t1
+
+    def _finish(self, batch, engine, k: int, level: int, step_s: float,
+                **fields) -> None:
+        """Record the step that completed ``batch`` and link every answered
+        request to it."""
+        rec = StepRecord(
+            n_requests=len(batch),
+            k=k,
+            bucket=batch_bucket(len(batch), engine.policy.batch_buckets),
+            step_s=step_s,
+            compile_count=self.executables,
+            level=level,
+            **fields,
+        )
+        for r in batch:
+            if r.done:
+                r.step = rec
+        self.completed.extend(batch)
+        self.steps.append(rec)
 
     def run_until_drained(self) -> list[AnnRequest]:
         while self.queue:
@@ -674,6 +718,7 @@ class _Inflight:
     batch: list[AnnRequest]
     k: int
     result: QueryResult
+    stats: QueryStats | None
     t_dispatch: float
     dispatch_s: float
     level: int = 0
@@ -728,13 +773,10 @@ class AsyncAnnServer(AnnServer):
         now = self.clock()
         level = self._serving_level(now)
         engine = self._engine_for(level)
-        batch, k = self._form_batch()
-        t0 = self.clock()
-        for r in batch:
-            r.t_start = t0
-        qs = [np.asarray(r.query) for r in batch]  # jaxlint: sync-ok — host payload
+        batch, k, t0, q = self._form()
         try:
-            res = self._query_with_retry(engine, batch, np.stack(qs), k)
+            with span("serve.dispatch"):
+                res, stats = self._query_with_retry(engine, batch, q, k)
         except ValueError as e:
             # Validation failures surface here, before anything reaches the
             # device: the malformed micro-batch completes-with-error and the
@@ -742,74 +784,42 @@ class AsyncAnnServer(AnnServer):
             t1 = self.clock()
             for r in batch:
                 r.error, r.t_done = str(e), t1
-            self.completed.extend(batch)
-            self.steps.append(
-                StepRecord(
-                    n_requests=len(batch),
-                    k=k,
-                    bucket=batch_bucket(len(batch), engine.policy.batch_buckets),
-                    step_s=t1 - t0,
-                    compile_count=self.executables,
-                    dispatch_s=t1 - t0,
-                    level=level,
-                )
-            )
+            self._finish(batch, engine, k, level, t1 - t0, dispatch_s=t1 - t0)
             return
         except Exception:
             # Retries exhausted: isolate per request, in front of the
             # in-flight window (these requests never reached the device).
             self._isolate(engine, batch, k, level)
             t1 = self.clock()
-            self.completed.extend(batch)
-            self.steps.append(
-                StepRecord(
-                    n_requests=len(batch),
-                    k=k,
-                    bucket=batch_bucket(len(batch), engine.policy.batch_buckets),
-                    step_s=t1 - t0,
-                    compile_count=self.executables,
-                    dispatch_s=t1 - t0,
-                    level=level,
-                )
-            )
+            self._finish(batch, engine, k, level, t1 - t0, dispatch_s=t1 - t0)
             return
         self._inflight.append(
-            _Inflight(batch, k, res, t0, dispatch_s=self.clock() - t0, level=level)
+            _Inflight(batch, k, res, stats, t0, dispatch_s=self.clock() - t0,
+                      level=level)
         )
 
     def _retire(self) -> list[AnnRequest]:
         """Materialise the oldest in-flight batch (blocks until it is done)."""
         fl = self._inflight.popleft()
+        counters: dict = {}
         try:
             # The ONE intentional blocking point of the async hot path:
-            # retiring the oldest in-flight batch materialises its results.
-            ids = np.asarray(fl.result.ids)  # jaxlint: sync-ok — the retire point
-            dists = np.asarray(fl.result.dists)  # jaxlint: sync-ok
-            t1 = self.clock()
-            qb = self._quality_bound(fl.level, fl.k)
-            for i, r in enumerate(fl.batch):
-                r.ids, r.dists, r.t_done = ids[i], dists[i], t1
-                r.degrade_level, r.quality_bound = fl.level, qb
+            # retiring the oldest in-flight batch waits for its results;
+            # the answers and counters are then copied out.
+            with span("serve.wait"):
+                jax.block_until_ready(fl.result)  # jaxlint: sync-ok — the retire point
+            with span("serve.deliver"):
+                t1 = self._deliver(fl.batch, fl.result, fl.level, fl.k)
+                counters = _counters(fl.stats)
         except Exception as e:
             # A batch that poisons materialisation fails alone; batches
             # behind it in the window are unaffected.
             t1 = self.clock()
             for r in fl.batch:
                 r.error, r.t_done = f"{type(e).__name__}: {e}", t1
-        self.completed.extend(fl.batch)
-        self.steps.append(
-            StepRecord(
-                n_requests=len(fl.batch),
-                k=fl.k,
-                bucket=batch_bucket(
-                    len(fl.batch),
-                    self._engine_for(fl.level).policy.batch_buckets,
-                ),
-                step_s=t1 - fl.t_dispatch,
-                compile_count=self.executables,
-                dispatch_s=fl.dispatch_s,
-                level=fl.level,
-            )
+        self._finish(
+            fl.batch, self._engine_for(fl.level), fl.k, fl.level,
+            t1 - fl.t_dispatch, dispatch_s=fl.dispatch_s, **counters,
         )
         return fl.batch
 
@@ -851,6 +861,29 @@ class AsyncAnnServer(AnnServer):
             self.step()
         self.flush()
         return self.completed
+
+
+def _query_with_stats(
+    engine, q, k: int
+) -> tuple[QueryResult, QueryStats | None]:
+    """``engine.query_with_stats`` where the engine's class has it.  A proxy
+    that wraps only ``query`` (a fault injector delegating the rest) is
+    called through ``query`` and reports no counters."""
+    if hasattr(type(engine), "query_with_stats"):
+        return engine.query_with_stats(q, k)
+    return engine.query(q, k=k), None
+
+
+def _counters(stats: QueryStats | None) -> dict:
+    """:class:`StepRecord` counter fields of a finished batch's stats."""
+    if stats is None:
+        return {}
+    return dict(
+        overflow_chunks=int(stats.overflow_chunks),
+        overflow_rows=int(stats.overflow_rows),
+        chunks=stats.chunks,
+        rows=stats.rows,
+    )
 
 
 def latency_summary(requests: Sequence[AnnRequest]) -> dict:
