@@ -236,14 +236,19 @@ _MERGE_IMPLS = ("topk", "sort", "counting", "auto")
 
 
 def _resolve_merge_impl(impl: str, score_dtype, smax: int | None) -> str:
-    """``impl="auto"`` picks counting exactly when the scores are declared
-    integer-ranged (integer dtype + a ``smax`` bound), else ``top_k``."""
+    """``impl="auto"`` picks by platform: the XLA sort on the TPU, where it
+    is the fastest merge and the counting select's searchsorted passes
+    are slow lane gathers; elsewhere counting exactly when the scores are
+    declared integer-ranged (integer dtype + a ``smax`` bound), else
+    ``top_k``."""
     if impl not in _MERGE_IMPLS:
         raise ValueError(
             f"impl must be one of {_MERGE_IMPLS}, got {impl!r}"
         )
     integer = jnp.issubdtype(score_dtype, jnp.integer)
     if impl == "auto":
+        if jax.default_backend() == "tpu":
+            return "sort"
         return "counting" if (smax is not None and integer) else "topk"
     if impl == "counting":
         if smax is None:
@@ -296,16 +301,19 @@ def merge_topk_pool(
     output — by inverting the sorted-merge positions with binary searches
     (:func:`_merge_sorted_desc`).  No comparison sort, no ``top_k``, no
     scatter; ~1.4x faster than ``top_k`` at the fused pruned width and
-    ~3x at full streaming widths on CPU.  Bit-compatible with
+    ~3x at full streaming widths on CPU.  On the TPU its searchsorted
+    passes lower to lane gathers and it is far slower than the XLA sort
+    (``PERF.md`` section 6).  Bit-compatible with
     ``impl="topk"`` on *any* input whose pool segment is score-descending
     (ties break to the earlier position, exactly ``top_k``'s rule), and
     therefore with ``"sort"`` under the streaming invariant above.
     Requires ``smax`` (scores must lie in ``[-1, smax]`` — out-of-range
     scores are silently dropped) and an integer score dtype.
 
-    ``impl="auto"`` resolves to ``"counting"`` exactly when the scores
-    are declared integer-ranged (integer dtype and ``smax`` given), else
-    to ``"topk"``.
+    ``impl="auto"`` resolves to ``"sort"`` on the TPU, the fastest of
+    the four there (``PERF.md`` section 6).  Elsewhere it resolves
+    to ``"counting"`` when the scores are declared integer-ranged (integer
+    dtype and ``smax`` given), else to ``"topk"``.
     """
     impl = _resolve_merge_impl(impl, pool_scores.dtype, smax)
     p = pool_scores.shape[-1]
@@ -344,7 +352,8 @@ def merge_topk_pool_with_dists(
     pool ids) and equally for a block pre-sorted by (score desc, id asc),
     the fused overflow fallback's shape.  ``"counting"`` is the integer
     counting-select (requires ``smax``); ``"sort"`` the two-key reference
-    sort; ``"auto"`` picks counting iff the scores are integer-ranged.
+    sort; ``"auto"`` picks sort on the TPU, and elsewhere counting iff the
+    scores are integer-ranged.
     The pre-computed exact distances simply ride along through the same
     gather, so the post-scan rerank gather over ``x`` is never needed.
     Sentinel entries carry ``dist = +inf``.
@@ -514,7 +523,8 @@ def jaxlint_entries():
             rules=("no-scatter-in-scan", "pinned-accumulator"),
             note=(
                 "the fused engine's joint (score, dist, id) pool merge with "
-                "impl='auto' resolving to counting — the serving default"
+                "impl='auto' resolving to counting — the serving default "
+                "off the TPU"
             ),
         ),
     ]

@@ -936,8 +936,9 @@ def suco_query_streaming(
     (:func:`sc_scores_cells`), and a carried per-query top pool is merged
     under the (score desc, id asc) order — ``merge_impl`` picks the merge
     algorithm (:func:`repro.core.sc_linear.merge_topk_pool`; "auto"
-    resolves to the counting-select over the integer ``0..Ns`` score
-    range, bit-identical to ``top_k``).  After the scan the pool equals
+    resolves to the two-key sort on the TPU and elsewhere to the
+    counting-select over the integer ``0..Ns`` score range, bit-identical
+    to ``top_k``).  After the scan the pool equals
     the dense ``top_k(scores, n_candidates)`` selection exactly (sentinels
     at score -1 / id INT32_MAX lose to every real point), so the exact
     re-rank returns the same ids/distances as :func:`suco_query`.
@@ -1044,7 +1045,8 @@ def suco_query_fused(
        width ``pool + survivor_cap`` instead of ``pool + block_n``
        (:func:`repro.core.sc_linear.merge_topk_pool_with_dists`;
        ``merge_impl`` selects the algorithm, "auto" resolving to the
-       counting-select over the integer ``0..Ns`` score range).
+       two-key sort on the TPU and elsewhere to the counting-select over
+       the integer ``0..Ns`` score range).
 
     A chunk whose survivor count exceeds ``survivor_cap`` for any query
     (cold pool on the first chunks, adversarial score ties) falls back via
@@ -1257,8 +1259,9 @@ def suco_query(
     ``merge_impl`` ("auto" | "topk" | "sort" | "counting") selects the
     pool-merge algorithm for the streaming/fused paths
     (:func:`repro.core.sc_linear.merge_topk_pool`); every impl is
-    bit-identical, and "auto" resolves to the counting-select over the
-    integer ``0..Ns`` score range.  The dense path ignores it.
+    bit-identical, and "auto" resolves to the two-key sort on the TPU and
+    elsewhere to the counting-select over the integer ``0..Ns`` score
+    range.  The dense path ignores it.
     """
     res = _query(
         x, index, q, k=k, alpha=alpha, beta=beta, metric=metric, mode=mode,

@@ -29,6 +29,8 @@ from repro.core import (
     suco_query_fused,
     suco_query_streaming,
 )
+from repro.core.sc_linear import _resolve_merge_impl
+from repro.core.suco import _fused_query
 from repro.data import make_dataset
 
 INT_MAX = np.iinfo(np.int32).max
@@ -75,6 +77,44 @@ def test_fused_matches_dense_and_streaming_bitwise(small, tiles):
     fused = suco_query_fused(x, idx, q, k=10, alpha=0.05, beta=0.02, tiles=tiles)
     _assert_bitwise_equal(dense, fused)
     _assert_bitwise_equal(stream, fused)
+
+
+@pytest.mark.parametrize(
+    "tiles",
+    [
+        None,
+        TileConfig(block_n=512, survivor_cap=64),  # pruned and fallback chunks
+        TileConfig(block_n=1000, survivor_cap=1),  # every chunk falls back
+    ],
+)
+def test_fused_sort_merge_matches_counting(small, tiles):
+    """``merge_impl="sort"`` — what "auto" runs on the TPU — serves the
+    counting merge's ids, distances and scores and counts the same
+    ``QueryStats``: the same pools carry, so the same chunks overflow."""
+    ds, x, idx = small
+    q = jnp.asarray(ds.queries)
+    kw = dict(k=10, alpha=0.05, beta=0.02, metric="l2", tiles=tiles,
+              score_impl="auto")
+    want, want_stats = _fused_query(x, idx, q, merge_impl="counting", **kw)
+    got, got_stats = _fused_query(x, idx, q, merge_impl="sort", **kw)
+    _assert_bitwise_equal(want, got)
+    assert int(got_stats.overflow_chunks) == int(want_stats.overflow_chunks)
+    assert int(got_stats.overflow_rows) == int(want_stats.overflow_rows)
+    assert (got_stats.chunks, got_stats.rows) == (want_stats.chunks, want_stats.rows)
+
+
+@pytest.mark.parametrize(
+    "backend, integer, other",
+    [("cpu", "counting", "topk"), ("gpu", "counting", "topk"),
+     ("tpu", "sort", "sort")],
+)
+def test_auto_merge_resolves_by_platform(monkeypatch, backend, integer, other):
+    """"auto" takes the two-key sort on the TPU; elsewhere the counting
+    select for integer-ranged scores and top_k for any other scores."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert _resolve_merge_impl("auto", jnp.int32, 8) == integer
+    assert _resolve_merge_impl("auto", jnp.int32, None) == other
+    assert _resolve_merge_impl("auto", jnp.float32, 8) == other
 
 
 def test_fused_tie_break_determinism():

@@ -16,7 +16,10 @@ to pin the three algebraic facts the serving subsystem is built on:
   at every score level, all-equal scores, duplicate ids across pool and
   block, non-divisible widths, pools down to ``p=1`` — with and without
   carried distances, and ``impl="auto"`` resolves to it exactly when the
-  scores are integer-ranged.
+  scores are integer-ranged.  The two-key **sort** impl, what
+  ``impl="auto"`` resolves to on the TPU, is pinned bitwise equal to
+  ``top_k`` on the two block shapes the fused scan merges: pruned
+  (ascending-id survivors, unsorted scores) and ``top_k``-sorted.
 * ``batch_bucket`` **padding never changes results**: the rowwise
   distance path is bitwise invariant to zero-padded batch rows, which is
   the exact property that makes a padded engine bucket return the
@@ -88,7 +91,7 @@ def _score_matrix(draw):
 
 
 @given(_score_matrix())
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 def test_merge_topk_pool_chunking_invariant(case):
     """Any ascending-id chunking == the dense lexicographic selection, for
     both impls, including pools larger than the data (sentinel tail)."""
@@ -110,7 +113,7 @@ def test_merge_topk_pool_chunking_invariant(case):
 
 
 @given(_score_matrix())
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_merge_topk_pool_order_invariant_with_sort_impl(case):
     """impl="sort" owes callers arbitrary block order: reversing the block
     arrival order must produce the identical pool."""
@@ -125,7 +128,7 @@ def test_merge_topk_pool_order_invariant_with_sort_impl(case):
 
 
 @given(_score_matrix())
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_merge_topk_pool_idempotent_on_exhausted_stream(case):
     """A merged pool is a fixed point: merging all-sentinel blocks (an
     exhausted stream) any number of times returns the pool bit-for-bit."""
@@ -184,7 +187,7 @@ def _sorted_pool_and_block(draw):
 
 
 @given(_sorted_pool_and_block())
-@settings(max_examples=30)
+@settings(max_examples=30, deadline=None)
 def test_counting_merge_bitwise_equals_topk(case):
     """The counting-select merge is a drop-in for the lax.top_k baseline:
     bit-identical pools for every tie structure (ties at every score
@@ -203,7 +206,7 @@ def test_counting_merge_bitwise_equals_topk(case):
 
 
 @given(_sorted_pool_and_block())
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 def test_counting_merge_with_dists_bitwise_equals_topk(case):
     """Same contract for the fused engine's joint (score, dist, id) pool:
     the carried exact distances ride the identical selection."""
@@ -212,6 +215,64 @@ def test_counting_merge_with_dists_bitwise_equals_topk(case):
     want = merge_topk_pool_with_dists(*args, impl="topk")
     got = merge_topk_pool_with_dists(*args, impl="counting", smax=smax)
     for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@st.composite
+def _streaming_pool_and_block(draw):
+    """A carried pool and one block in a shape the fused scan merges: the
+    pool sorted by (score desc, id asc) with unique ids and a sentinel
+    tail; the block's ids above every pool id, either as pruned survivor
+    slots (ascending ids, unsorted scores, dead slots at the sentinel) or
+    sorted by score descending as the overflow fallback's ``lax.top_k``
+    returns it.  Ties at every level, all-equal scores, ``p`` down to 1 and
+    blocks wider and narrower than the pool come from
+    :func:`_sorted_pool_and_block`."""
+    ps, pd, pi, bs, bd, _bi, smax = draw(_sorted_pool_and_block())
+    rng = np.random.default_rng(draw(st.integers(0, 2**31 - 1)))
+    m, p = ps.shape
+    bw = bs.shape[1]
+    ids = np.sort(
+        np.stack([rng.choice(50, size=p, replace=False) for _ in range(m)]),
+        axis=1,
+    )  # ascending along the score-descending pool: ties are id-ascending
+    pi = np.where(ps < 0, INT_MAX, ids).astype(np.int32)
+    dead = rng.random((m, bw)) < rng.random()  # up to every slot
+    bs = np.where(dead, -1, bs).astype(np.int32)
+    bi = np.where(dead, INT_MAX, 50 + np.arange(bw)[None, :]).astype(np.int32)
+    bd = np.where(dead, np.inf, bd).astype(np.float32)
+    if draw(st.booleans()):  # the fallback's top_k-sorted block
+        order = np.argsort(-bs, axis=1, kind="stable")
+        bs, bi, bd = (np.take_along_axis(a, order, 1) for a in (bs, bi, bd))
+    return ps, pd, pi, bs, bd, bi
+
+
+@given(_streaming_pool_and_block())
+@settings(max_examples=30, deadline=None)
+def test_sort_merge_bitwise_equals_topk(case):
+    """The two-key sort is a drop-in for the lax.top_k baseline on every
+    block the fused scan feeds it — ties at every level, all-equal scores,
+    sentinel-heavy pools and blocks, ``p`` not a power of two down to 1,
+    blocks wider and narrower than the pool."""
+    ps, _pd, pi, bs, _bd, bi = case
+    args = tuple(map(jnp.asarray, (ps, pi, bs, bi)))
+    want = merge_topk_pool(*args, impl="topk")
+    got = merge_topk_pool(*args, impl="sort")
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+@given(_streaming_pool_and_block())
+@settings(max_examples=30, deadline=None)
+def test_sort_merge_with_dists_bitwise_equals_topk(case):
+    """Same contract for the joint (score, dist, id) pool: the distances
+    ride the sort as a third operand and come out bit-identical."""
+    args = tuple(map(jnp.asarray, case))
+    want = merge_topk_pool_with_dists(*args, impl="topk")
+    got = merge_topk_pool_with_dists(*args, impl="sort")
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
 
 
@@ -227,7 +288,7 @@ def _padded_batch(draw):
 
 
 @given(_padded_batch())
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 def test_bucket_padding_never_changes_rowwise_distances(case):
     """The serving-path distance impl is bitwise invariant to the zero rows
     :func:`batch_bucket` padding appends — the property that makes padded

@@ -113,6 +113,9 @@ def test_kmeans_kernels_compile_at_build_width(shape, op):
 
 
 def test_fused_query_compiles_at_sift1m(shape):
+    """The fused query at the SIFT1M widths with the kernels and the pool
+    merge the TPU runs: ``merge_impl="auto"`` resolves to ``"sort"``
+    there (``tests/test_fused_query.py`` checks that resolution)."""
     m = 64
     spec = sub.contiguous_spec(D, NS)
     index = SuCoIndex(
@@ -127,7 +130,7 @@ def test_fused_query_compiles_at_sift1m(shape):
     def query(x, idx, q):
         return suco_query_fused(
             x, idx, q, k=TOPK, alpha=ALPHA, beta=BETA, tiles=_tiles(m),
-            score_impl="pallas",
+            score_impl="pallas", merge_impl="sort",
         )
 
     hlo, nbytes = _compile(query, shape((N, D)), index, shape((m, D)))
