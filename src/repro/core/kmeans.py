@@ -34,6 +34,13 @@ Index-build memory model (three execution paths, one reference semantics):
 The final assignment pass respects ``impl`` ("auto" routes to the fused
 Pallas ``kmeans_assign`` kernels on TPU, pure jnp elsewhere) and is
 chunked whenever ``block_n>0``.
+
+Every path reads the points in one layout, ``xt (B, s, n_pad)``: points on
+the lanes, the first ``n`` columns real and the rest zero — what the
+kernels stream.  :func:`kmeans_batched_t` takes it as given (the index
+build writes it once, padded to :func:`lane_points`, so no pass copies,
+transposes or re-pads it); :func:`kmeans_batched` and :func:`kmeans`
+transpose their ``(B, n, s)`` / ``(n, s)`` input into it once.
 """
 
 from __future__ import annotations
@@ -50,6 +57,8 @@ __all__ = [
     "KMeansResult",
     "kmeans",
     "kmeans_batched",
+    "kmeans_batched_t",
+    "lane_points",
     "assign",
     "block_batched",
     "lloyd_stats_scan",
@@ -116,16 +125,21 @@ def init_centroids_pp(
     full dataset before its final assignment pass, and the seeding keeps
     that property.  O(sample_n * k) work; deterministic given ``key``.
     """
-    n = x.shape[0]
+    return _pp_lanes(key, x.T, x.shape[0], k, sample_n)
+
+
+def _pp_lanes(key: jax.Array, xt: jax.Array, n: int, k: int, sample_n: int) -> jax.Array:
+    """:func:`init_centroids_pp` over points on lanes, ``xt (s, >= n)``:
+    the same draws, so the same seeds."""
     k_sub, k_first, k_pick = jax.random.split(key, 3)
     if 0 < sample_n < n:
         idx = jax.random.permutation(k_sub, n)[:sample_n]
-        xs = jnp.take(x, idx, axis=0)
+        xs = jnp.take(xt, idx, axis=1).T
     else:
-        xs = x
+        xs = xt[:, :n].T
     xf = xs.astype(jnp.float32)
     c0 = xf[jax.random.randint(k_first, (), 0, xs.shape[0])]
-    cents = jnp.zeros((k, x.shape[1]), jnp.float32).at[0].set(c0)
+    cents = jnp.zeros((k, xs.shape[1]), jnp.float32).at[0].set(c0)
     d2 = jnp.sum((xf - c0) ** 2, axis=-1)
 
     def body(carry, inp):
@@ -145,13 +159,45 @@ def init_centroids_pp(
         (cents, d2),
         (jnp.arange(1, k), jax.random.split(k_pick, k - 1)),
     )
-    return cents.astype(x.dtype)
+    return cents.astype(xt.dtype)
+
+
+def _lane_columns(xt: jax.Array, cols: jax.Array) -> jax.Array:
+    """``xt (B, s, N), cols (B, k) -> (B, k, s)``: point ``cols[j, t]`` of
+    codebook ``j``.  The lane-wide ``(s, 128)`` tile holding each point is
+    sliced out of ``xt``, and the point's column is picked from those
+    small tiles: a gather, or a one-lane slice, along the lane axis of
+    ``xt`` itself would have the compiler lay the whole array out again
+    for it (points major, widths padded to 128 lanes)."""
+    b, s, n_cols = xt.shape
+    k = cols.shape[1]
+    w = min(128, n_cols)
+    flat = cols.reshape(-1)
+    books = jnp.repeat(jnp.arange(b, dtype=flat.dtype), k)
+    starts = jnp.minimum(flat - flat % w, n_cols - w)
+    tiles = jax.lax.map(
+        lambda js: jax.lax.dynamic_slice(xt, (js[0], 0, js[1]), (1, s, w))[0],
+        (books, starts),
+    )  # (B*k, s, w)
+    picked = jnp.take_along_axis(tiles, (flat - starts)[:, None, None], axis=2)
+    return picked.reshape(b, k, s)
 
 
 def _init_batched(
     key: jax.Array, xs: jax.Array, k: int, init: str, algo: str
 ) -> jax.Array:
-    """``(B, n, s) -> (B, k, s)`` initial centroids for every problem.
+    """``(B, n, s) -> (B, k, s)`` initial centroids for every problem
+    (:func:`_init_lanes` on the transposed points)."""
+    return _init_lanes(key, jnp.swapaxes(xs, 1, 2), xs.shape[1], k, init, algo)
+
+
+def _init_lanes(
+    key: jax.Array, xt: jax.Array, n: int, k: int, init: str, algo: str
+) -> jax.Array:
+    """``xt (B, s, >= n) -> (B, k, s)`` initial centroids for every problem,
+    reading only the ``k`` (or kmeans++'s sampled) point columns: codebook
+    ``j`` draws with key ``split(key, B)[j]``, and random init takes the
+    first ``k`` points of ``jax.random.permutation`` of ``n``.
 
     ``init="auto"`` resolves to kmeans++ for minibatch (whose few sampled
     steps cannot recover from a bad random seed the way full Lloyd epochs
@@ -159,13 +205,12 @@ def _init_batched(
     mode = init
     if mode == "auto":
         mode = "kmeans++" if algo == "minibatch" else "random"
-    keys = jax.random.split(key, xs.shape[0])
+    keys = jax.random.split(key, xt.shape[0])
     if mode == "random":
-        return jax.vmap(lambda kk, x: _init_centroids(kk, x, k))(keys, xs)
-    sample_n = min(xs.shape[1], max(_PP_SAMPLE_PER_K * k, _PP_SAMPLE_MIN))
-    return jax.vmap(
-        lambda kk, x: init_centroids_pp(kk, x, k, sample_n=sample_n)
-    )(keys, xs)
+        rows = jax.vmap(lambda kk: jax.random.permutation(kk, n)[:k])(keys)
+        return _lane_columns(xt, rows)
+    sample_n = min(n, max(_PP_SAMPLE_PER_K * k, _PP_SAMPLE_MIN))
+    return jax.vmap(lambda kk, xb: _pp_lanes(kk, xb, n, k, sample_n))(keys, xt)
 
 
 # --------------------------------------------------------------------------
@@ -310,25 +355,82 @@ def assign_scan(
     return a, inertia, counts if pair_sqrt_k else None
 
 
-def _stats_batched(
-    xs: jax.Array, centroids: jax.Array, *, block_n: int, impl: str
+def _lane_blocks(xt: jax.Array, n: int, bn: int) -> tuple[jax.Array, jax.Array]:
+    """``xt (B, s, nb*bn)`` points on lanes -> :func:`block_batched`'s
+    ``(blocks (nb, B, bn, s), valid (nb, bn))``: the jnp scans' chunks."""
+    b, s, n_pad = xt.shape
+    nb = n_pad // bn
+    blocks = xt.reshape(b, s, nb, bn).transpose(2, 0, 3, 1)
+    valid = (jnp.arange(n_pad) < n).reshape(nb, bn)
+    return blocks, valid
+
+
+def _stats_chunk(
+    xb: jax.Array, centroids: jax.Array, *, impl: str
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Dispatch one batched Lloyd statistics pass: fused Pallas kernel on
-    TPU, blocked jnp scan elsewhere."""
+    """One batched Lloyd statistics pass over a chunk on lanes, ``xb (B,
+    s, bn)``: fused Pallas kernel on TPU, blocked jnp scan elsewhere."""
+    bn = xb.shape[2]
     if _use_pallas(impl):
         from repro.kernels.kmeans_assign import ops as _ops
 
-        # impl="pallas": the dispatch decision is already made — forward it
-        # so an explicit off-TPU request runs the kernel (or fails loudly)
-        # instead of silently falling back to the dense jnp oracle.
-        # with_assign=False: Lloyd iterations consume only the statistics,
-        # and an unused pallas output cannot be DCE'd.
-        _, sums, counts, inertia = _ops.kmeans_assign_stats(
-            xs, centroids, bn=block_n, impl="pallas", with_assign=False
+        # impl="pallas": the dispatch decision is already made — the
+        # kernel runs (or fails loudly) instead of silently falling back
+        # to the dense jnp oracle.  with_assign=False: the update consumes
+        # only the statistics, and an unused pallas output cannot be DCE'd.
+        _, sums, counts, inertia = _ops.kmeans_assign_stats_t(
+            xb, centroids, n=bn, bn=bn, with_assign=False
         )
         return sums, counts, inertia
-    blocks, valid = block_batched(xs, block_n)
+    blocks, valid = _lane_blocks(xb, bn, bn)
     return lloyd_stats_scan(blocks, valid, centroids)
+
+
+def _point_plan(
+    n: int, b: int, s: int, k: int, algo: str, block_n: int, pallas: bool
+) -> tuple[int, int]:
+    """``(bn, n_pad)``: the chunk every full-data pass streams, and the
+    point columns of the layout those passes read without a copy."""
+    if algo == "minibatch":
+        bn = max(1, min(block_n or _MINIBATCH_DEFAULT_BLOCK, n))
+    elif pallas:
+        bn = block_n or 1024
+    else:
+        bn = max(1, min(block_n, n)) if block_n else n
+    if pallas:
+        from repro.kernels.kmeans_assign import ops as _ops
+
+        bn_kernel = _ops.lane_block(b, s, k, n, bn)
+        return bn, -(-n // bn_kernel) * bn_kernel
+    return bn, -(-n // bn) * bn
+
+
+def lane_points(
+    n: int,
+    b: int,
+    s: int,
+    k: int,
+    *,
+    algo: str = "lloyd",
+    block_n: int = 0,
+    impl: str = "auto",
+) -> int:
+    """Point columns :func:`kmeans_batched_t` streams without a copy, for
+    ``b`` codebooks of ``k`` centroids over ``s``-wide subspaces of ``n``
+    points: lay the points out with this many (zero-padded) columns and no
+    pass re-pads them."""
+    return _point_plan(n, b, s, k, algo, block_n, _use_pallas(impl))[1]
+
+
+def _fit_lanes(xt: jax.Array, n_pad: int) -> jax.Array:
+    """``xt`` cut or zero-padded to ``n_pad`` point columns (no-op when the
+    caller laid it out with :func:`lane_points` columns)."""
+    have = xt.shape[2]
+    if have > n_pad:
+        return xt[:, :, :n_pad]
+    if have < n_pad:
+        return jnp.pad(xt, ((0, 0), (0, 0), (0, n_pad - have)))
+    return xt
 
 
 # --------------------------------------------------------------------------
@@ -338,7 +440,8 @@ def _stats_batched(
 
 def _kmeans_core(
     key: jax.Array,
-    xs: jax.Array,  # (B, n, s)
+    xt: jax.Array,  # (B, s, >= n) points on lanes, zero columns from n on
+    n: int,
     c0: jax.Array,  # (B, k, s)
     iters: int,
     algo: str,
@@ -346,19 +449,20 @@ def _kmeans_core(
     impl: str,
     pair_sqrt_k: int = 0,
 ) -> KMeansResult:
-    b, n, s = xs.shape
+    b, s, _ = xt.shape
     k = c0.shape[1]
     pallas = _use_pallas(impl)
+    bn, n_pad = _point_plan(n, b, s, k, algo, block_n, pallas)
+    xt = _fit_lanes(xt, n_pad)
 
     if algo == "minibatch":
-        bn = max(1, min(block_n or _MINIBATCH_DEFAULT_BLOCK, n))
 
         def mb_body(carry, t):
             c, cnts = carry
             kt = jax.random.fold_in(key, t)
             idx = jax.random.randint(kt, (bn,), 0, n)
-            xb = jnp.take(xs, idx, axis=1)  # (B, bn, s) — shared sample
-            sums, counts, _ = _stats_batched(xb, c, block_n=bn, impl=impl)
+            xb = jnp.take(xt, idx, axis=2)  # (B, s, bn) — shared sample
+            sums, counts, _ = _stats_chunk(xb, c, impl=impl)
             cnts = cnts + counts
             # Aggregated Sculley update: per-centroid learning rate
             # counts / cnts, i.e. c <- c + (batch_sum - batch_count*c)/cnts.
@@ -375,7 +479,7 @@ def _kmeans_core(
             (c0, jnp.zeros((b, k), jnp.float32)),
             jnp.arange(iters, dtype=jnp.int32),
         )
-        a, inertia, counts = _final_assign(xs, c_fin, block_n=bn, pallas=pallas,
+        a, inertia, counts = _final_assign(xt, n, c_fin, bn=bn, pallas=pallas,
                                            need_inertia=True,
                                            pair_sqrt_k=pair_sqrt_k)
         return KMeansResult(c_fin, a, inertia, counts)
@@ -388,7 +492,11 @@ def _kmeans_core(
             new, inertia = jax.vmap(_lloyd_step)(xs, c)
             return new, inertia
         if pallas:
-            sums, counts, inertia = _stats_batched(xs, c, block_n=block_n, impl=impl)
+            from repro.kernels.kmeans_assign import ops as _ops
+
+            _, sums, counts, inertia = _ops.kmeans_assign_stats_t(
+                xt, c, n=n, bn=bn, with_assign=False
+            )
         else:
             sums, counts, inertia = lloyd_stats_scan(blocks, valid, c)
         new = sums / jnp.maximum(counts, 1.0)[..., None]
@@ -396,63 +504,60 @@ def _kmeans_core(
         return new.astype(c.dtype), inertia
 
     with scope("kmeans.lloyd"):
-        if chunked and not pallas:
-            blocks, valid = block_batched(xs, block_n)
+        if not chunked:  # the dense reference steps on (B, n, s) rows
+            xs = jnp.swapaxes(xt[:, :, :n], 1, 2)
+        elif not pallas:
+            blocks, valid = _lane_blocks(xt, n, bn)
         centroids, inertias = jax.lax.scan(lloyd_body, c0, None, length=iters)
     with scope("kmeans.assign"):
         a, _, counts = _final_assign(
-            xs, centroids, block_n=block_n, pallas=pallas,
+            xt, n, centroids, bn=bn, pallas=pallas,
             need_inertia=False, pair_sqrt_k=pair_sqrt_k,
         )
     return KMeansResult(centroids, a, inertias[-1], counts)
 
 
 def _final_assign(
-    xs: jax.Array,
+    xt: jax.Array,
+    n: int,
     centroids: jax.Array,
     *,
-    block_n: int,
+    bn: int,
     pallas: bool,
     need_inertia: bool,
     pair_sqrt_k: int = 0,
 ) -> tuple[jax.Array, jax.Array | None, jax.Array | None]:
-    """Final assignment pass
-    -> (assign (B, n) int32, inertia (B,) f32|None, cell_counts|None).
+    """Final assignment pass over ``xt (B, s, n_pad)`` in ``bn``-point
+    chunks -> (assign (B, n) int32, inertia (B,) f32|None, cell_counts|None).
 
-    Routed through the batched Pallas kernels on TPU (regardless of
-    block_n: they stream internally), the chunked jnp scan when
-    ``block_n>0``, and the dense jnp argmin otherwise.  Lloyd callers pass
-    ``need_inertia=False`` (they report the last update step's inertia) so
-    the TPU path can use the assign-only kernel and skip the dead
-    one-hot/stats accumulation work entirely; minibatch needs the final
-    full-data inertia and takes the fused stats kernel.
+    Routed through the batched Pallas kernels on TPU, the chunked jnp scan
+    elsewhere (one chunk of all ``n`` points for the dense reference).
+    Lloyd callers pass ``need_inertia=False`` (they report the last update
+    step's inertia) so the TPU path can use the assign-only kernel and
+    skip the dead one-hot/stats accumulation work entirely; minibatch
+    needs the final full-data inertia and takes the fused stats kernel.
 
     ``pair_sqrt_k > 0`` fuses the SuCo IMI occupancy histogram into the
     scan (see :func:`assign_scan`).  The Lloyd-path TPU route fuses it
-    too (:func:`repro.kernels.kmeans_assign.ops.kmeans_pair_assign_hist`:
+    too (:func:`repro.kernels.kmeans_assign.ops.kmeans_pair_assign_hist_t`:
     the histogram accumulates on the MXU inside the assignment kernel);
     only the minibatch TPU path — which additionally needs the full-data
     inertia from the stats kernel — still returns None and leaves the
     caller a bincount over the assignments.
     """
-    b, n, _ = xs.shape
     if pallas:
         from repro.kernels.kmeans_assign import ops as _ops
 
-        bn = block_n or 1024
         if not need_inertia:
             if pair_sqrt_k:
-                a, counts = _ops.kmeans_pair_assign_hist(
-                    xs, centroids, bn=bn, impl="pallas"
+                a, counts = _ops.kmeans_pair_assign_hist_t(
+                    xt, centroids, n=n, bn=bn
                 )
                 return a, None, counts
-            a = _ops.kmeans_assign_batched(xs, centroids, bn=bn, impl="pallas")
-            return a, None, None
-        a, _, _, inertia = _ops.kmeans_assign_stats(
-            xs, centroids, bn=bn, impl="pallas"
-        )
+            return _ops.kmeans_assign_batched_t(xt, centroids, n=n, bn=bn), None, None
+        a, _, _, inertia = _ops.kmeans_assign_stats_t(xt, centroids, n=n, bn=bn)
         return a, inertia, None
-    blocks, valid = block_batched(xs, block_n or n)
+    blocks, valid = _lane_blocks(xt, n, bn)
     a, inertia, counts = assign_scan(blocks, valid, centroids,
                                      pair_sqrt_k=pair_sqrt_k)
     return a[:, :n], inertia, counts
@@ -498,7 +603,8 @@ def kmeans(
     else:
         sample_n = min(x.shape[0], max(_PP_SAMPLE_PER_K * k, _PP_SAMPLE_MIN))
         c0 = init_centroids_pp(key, x, k, sample_n=sample_n)
-    res = _kmeans_core(key, x[None], c0[None], iters, algo, block_n, impl)
+    res = _kmeans_core(key, x.T[None], x.shape[0], c0[None], iters, algo,
+                       block_n, impl)
     return KMeansResult(res.centroids[0], res.assignments[0], res.inertia[0])
 
 
@@ -519,11 +625,36 @@ def kmeans_batched(
     One fused program for all ``B`` codebooks (B = 2*Ns for SuCo); same
     ``algo``/``block_n``/``impl``/``init`` contract as :func:`kmeans`.
     ``pair_sqrt_k > 0`` additionally returns the fused IMI cell occupancy
-    ``KMeansResult.cell_counts`` from the final-assignment scan (jnp paths
-    only; the Pallas final assignment leaves it None — see
-    :func:`assign_scan`).
+    ``KMeansResult.cell_counts`` from the final-assignment pass (None on
+    the minibatch TPU path — see :func:`_final_assign`).  The points are
+    transposed once onto lanes (:func:`kmeans_batched_t`).
+    """
+    return kmeans_batched_t(
+        key, jnp.swapaxes(xs, 1, 2), k, iters, n=xs.shape[1], algo=algo,
+        block_n=block_n, impl=impl, init=init, pair_sqrt_k=pair_sqrt_k,
+    )
+
+
+def kmeans_batched_t(
+    key: jax.Array,
+    xt: jax.Array,
+    k: int,
+    iters: int,
+    *,
+    n: int,
+    algo: str = "lloyd",
+    block_n: int = 0,
+    impl: str = "auto",
+    init: str = "auto",
+    pair_sqrt_k: int = 0,
+) -> KMeansResult:
+    """:func:`kmeans_batched` over points on lanes: ``xt (B, s, n_pad)``,
+    of which the first ``n`` columns are the points and the rest are
+    zero.  The layout the TPU kernels stream: given
+    :func:`lane_points` columns, no Lloyd pass, final assignment or init
+    copies, transposes or re-pads the points.
     """
     _check_args(algo, block_n, init)
     with scope("kmeans.init"):
-        c0 = _init_batched(key, xs, k, init, algo)
-    return _kmeans_core(key, xs, c0, iters, algo, block_n, impl, pair_sqrt_k)
+        c0 = _init_lanes(key, xt, n, k, init, algo)
+    return _kmeans_core(key, xt, n, c0, iters, algo, block_n, impl, pair_sqrt_k)

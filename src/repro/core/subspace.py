@@ -32,6 +32,8 @@ __all__ = [
     "permute",
     "split_padded",
     "split_query_padded",
+    "half_dims",
+    "halves_on_lanes",
     "collision_count",
 ]
 
@@ -142,6 +144,65 @@ def split_halves_padded(spec: SubspaceSpec, x: jax.Array) -> tuple[jax.Array, ja
                 piece = jnp.pad(piece, widths)
             acc.append(piece)
     return jnp.stack(first, axis=0), jnp.stack(second, axis=0)
+
+
+def half_dims(spec: SubspaceSpec) -> np.ndarray:
+    """``(2Ns, h_max)`` original dimension of each half-subspace row: the
+    first halves of all subspaces, then all second halves (the layout of
+    :func:`split_halves_padded` concatenated); ``-1`` marks zero padding."""
+    ns, h_max = spec.n_subspaces, spec.max_half_size
+    perm = np.asarray(spec.perm)
+    out = np.full((2 * ns, h_max), -1, np.int64)
+    for i, ((a, b), (h1, h2)) in enumerate(
+        zip(zip(spec.bounds, spec.bounds[1:]), spec.half_sizes)
+    ):
+        out[i, :h1] = perm[a : a + h1]
+        out[ns + i, :h2] = perm[a + h1 : b]
+    return out
+
+
+def halves_on_lanes(
+    spec: SubspaceSpec,
+    x: jax.Array,
+    n_pad: int,
+    *,
+    kernel: bool = False,
+    columns: bool = False,
+) -> jax.Array:
+    """``(n, d) -> (2Ns, h_max, n_pad)``: the half-subspace views with the
+    points on the minor (lane) axis and zero columns from ``n`` on — the
+    layout the K-means kernels stream.  Row ``j`` equals
+    ``split_halves_padded`` (first halves, then second halves) transposed;
+    padded half dims are zero rows.
+
+    ``kernel=True`` (the TPU path) writes it with one Pallas pass over the
+    points (:func:`repro.kernels.kmeans_assign.ops.halves_to_lanes`), so
+    ``x`` and the result are all it holds; an XLA transpose into this
+    tiling keeps a second whole copy alive.  ``columns`` says ``x`` is
+    stored column-major, so the kernel reads ``x^T`` as stored.  Otherwise
+    it is a row gather of ``x^T``.
+    """
+    n, d = x.shape
+    if n_pad < n:
+        raise ValueError(f"n_pad={n_pad} < n={n}")
+    src = half_dims(spec)
+    b, h_max = src.shape
+    if kernel and n >= 128:  # at least one lane block of points
+        from repro.kernels.kmeans_assign.ops import halves_to_lanes
+
+        if spec.perm != tuple(range(d)):
+            x, columns = permute(spec, x), False
+        bounds = list(zip(spec.bounds, spec.bounds[1:]))
+        halves = spec.half_sizes
+        starts = [a for a, _ in bounds] + [a + h1 for (a, _), (h1, _) in
+                                           zip(bounds, halves)]
+        widths = [h1 for h1, _ in halves] + [h2 for _, h2 in halves]
+        return halves_to_lanes(x, tuple(starts), tuple(widths), h_max, n_pad,
+                               columns=columns)
+    rows = jnp.take(x.T, jnp.asarray(np.maximum(src, 0).reshape(-1)), axis=0)
+    keep = jnp.asarray((src >= 0).reshape(-1, 1))
+    xt = jnp.where(keep, rows, jnp.zeros((), x.dtype)).reshape(b, h_max, n)
+    return jnp.pad(xt, ((0, 0), (0, 0), (0, n_pad - n)))
 
 
 def collision_count(n: int, alpha: float) -> int:

@@ -65,7 +65,11 @@ Index-build memory model (mirrors the query design; see
   different fp order, so over multiple Lloyd iterations points sitting
   exactly on Voronoi boundaries can flip cells (in practice <0.1%; exact
   parity on separated data).  On TPU the pass is the fused Pallas
-  ``kmeans_assign_stats`` kernel.
+  ``kmeans_assign_stats`` kernel.  Every pass reads the half-subspace
+  points in the kernels' layout, ``(2Ns, h_max, n_pad)`` with the points
+  on lanes, written once per build from ``x``
+  (:func:`repro.core.subspace.halves_on_lanes`): the build holds ``x``
+  and that one copy.
 * **minibatch** (``build_mode="minibatch"``) — opt-in approximate mode
   for million-point builds: each K-means step assigns one sampled
   ``block_n`` chunk and applies learning-rate centroid updates; the
@@ -109,9 +113,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import kmeans
 from repro.core import subspace as sub
 from repro.core.distances import Metric, pairwise_dist
-from repro.core.kmeans import assign_scan, block_batched, kmeans_batched
+from repro.core.kmeans import (
+    assign_scan,
+    block_batched,
+    kmeans_batched_t,
+    lane_points,
+)
 from repro.core.sc_linear import (
     QueryResult,
     candidate_pool_size,
@@ -469,7 +479,8 @@ class SuCoIndex:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("spec", "sqrt_k", "iters", "algo", "block_n")
+    jax.jit,
+    static_argnames=("spec", "sqrt_k", "iters", "algo", "block_n", "columns"),
 )
 def _build(
     x: jax.Array,
@@ -480,18 +491,27 @@ def _build(
     iters: int,
     algo: str = "lloyd",
     block_n: int = 0,
+    columns: bool = False,
 ):
     ns = spec.n_subspaces
+    n = x.shape[0]
     with scope("halves"):
-        xp = sub.permute(spec, x)
-        h1, h2 = sub.split_halves_padded(spec, xp)  # 2 x (Ns, n, h_max)
-        both = jnp.concatenate([h1, h2], axis=0)  # (2Ns, n, h_max)
+        # One pass over x into the layout every K-means pass streams:
+        # (2Ns, h_max, n_pad), points on lanes, padded once to the passes'
+        # chunk — no pass copies, transposes or re-pads the points.
+        n_pad = lane_points(
+            n, 2 * ns, spec.max_half_size, sqrt_k, algo=algo, block_n=block_n
+        )
+        xt = sub.halves_on_lanes(
+            spec, x, n_pad, kernel=kmeans._use_pallas("auto"), columns=columns
+        )
     # block_n=0 is the dense reference; >0 streams every K-means pass —
     # including the final assignment feeding cell_ids — in block_n chunks.
     # pair_sqrt_k fuses the IMI occupancy histogram into that final
     # assignment scan, so cell_counts costs no extra pass over the data.
-    res = kmeans_batched(
-        key, both, sqrt_k, iters, algo=algo, block_n=block_n, pair_sqrt_k=sqrt_k
+    res = kmeans_batched_t(
+        key, xt, sqrt_k, iters, n=n, algo=algo, block_n=block_n,
+        pair_sqrt_k=sqrt_k,
     )
     a1, a2 = res.assignments[:ns], res.assignments[ns:]
     cell_ids = (a1 * sqrt_k + a2).astype(jnp.int32)  # (Ns, n)
@@ -502,6 +522,14 @@ def _build(
             lambda c: jnp.bincount(c, length=sqrt_k * sqrt_k).astype(jnp.int32)
         )(cell_ids)
     return res.centroids[:ns], res.centroids[ns:], cell_ids, counts
+
+
+def _stored_by_columns(x) -> bool:
+    """Whether the device holds ``x (n, d)`` column-major: the TPU picks
+    that layout for a corpus it would otherwise pad, as 1M x 960 f32 (960
+    is no multiple of 128 lanes).  Tracers and host arrays say no."""
+    layout = getattr(getattr(x, "format", None), "layout", None)
+    return getattr(layout, "major_to_minor", None) == (1, 0)
 
 
 def build_index(x: jax.Array, config: SuCoConfig, *, spec: sub.SubspaceSpec | None = None) -> SuCoIndex:
@@ -550,6 +578,7 @@ def build_index(x: jax.Array, config: SuCoConfig, *, spec: sub.SubspaceSpec | No
             iters=config.kmeans_iters,
             algo=algo,
             block_n=block_n,
+            columns=_stored_by_columns(x),
         )
     return SuCoIndex(c1, c2, cell_ids, counts, spec=spec, sqrt_k=config.sqrt_k)
 
@@ -884,11 +913,19 @@ class QueryStats:
     ``survivor_cap``, so the scan took the exact fallback for the whole
     chunk.  ``overflow_rows``: (row, chunk) pairs whose own survivors
     overflowed — the part of that fallback a per-row one would still do.
-    ``chunks`` and ``rows`` are the scan's static extent.
+    ``rerank_rows``: rows of ``x`` the scan gathered to rerank — ``m *
+    survivor_cap`` per pruned chunk, ``m * min(pool, block_n)`` per
+    fallback chunk, over the padded batch of ``m`` rows.  ``rerank_live``:
+    those of them that held a live candidate (a survivor slot below the
+    row's survivor count, a fallback pick that is a real column); the rest
+    were gathered for a dead slot.  ``chunks`` and ``rows`` are the scan's
+    static extent.
     """
 
     overflow_chunks: jax.Array  # () int32
     overflow_rows: jax.Array  # () int32
+    rerank_rows: jax.Array  # () int32
+    rerank_live: jax.Array  # () int32
     chunks: int = dataclasses.field(metadata=dict(static=True))
     rows: int = dataclasses.field(metadata=dict(static=True))
 
@@ -1167,10 +1204,11 @@ def _fused_query(
                 )
                 dists = jnp.where(live, dists, inf)
             with scope("merge"):
-                return merge_topk_pool_with_dists(
+                merged = merge_topk_pool_with_dists(
                     pool_s, pool_d, pool_i, surv_sm, dists, surv_i,
                     impl=merge_impl, smax=index.spec.n_subspaces,
                 )
+            return merged, jnp.sum(live, dtype=jnp.int32)
 
         def full_merge(_):
             # Exact overflow fallback: the merged top-pool can absorb at
@@ -1188,22 +1226,23 @@ def _fused_query(
                     top_i, x, q, metric=metric, impl="jnp"
                 )
                 dists = jnp.where(top_i == int_max, inf, dists)
-                return merge_topk_pool_with_dists(
+                merged = merge_topk_pool_with_dists(
                     pool_s, pool_d, pool_i, top_s, dists, top_i,
                     impl=merge_impl, smax=index.spec.n_subspaces,
                 )
+                return merged, jnp.sum(top_i != int_max, dtype=jnp.int32)
 
         over = total > cap  # (m,) rows whose survivors overflow the cap
         overflow = jnp.any(over)
-        merged = jax.lax.cond(overflow, full_merge, pruned_merge, None)
-        return merged, (overflow, jnp.sum(over, dtype=jnp.int32))
+        merged, live_rows = jax.lax.cond(overflow, full_merge, pruned_merge, None)
+        return merged, (overflow, jnp.sum(over, dtype=jnp.int32), live_rows)
 
     init = (
         jnp.full((m, pool), -1, jnp.int32),
         jnp.full((m, pool), inf, dist_dtype),
         jnp.full((m, pool), int_max, jnp.int32),
     )
-    (pool_s, pool_d, pool_i), (over_chunk, over_rows) = jax.lax.scan(
+    (pool_s, pool_d, pool_i), (over_chunk, over_rows, live_rows) = jax.lax.scan(
         step, init, (jnp.arange(n_blocks, dtype=jnp.int32), cells, keep_blocks)
     )
     # Final selection == rerank_candidates' top_k on the carried pool:
@@ -1215,9 +1254,14 @@ def _fused_query(
             -neg,
             jnp.take_along_axis(pool_s, pos, axis=-1),
         )
+    # Rows of x each chunk gathered to rerank: every survivor slot of the
+    # pruned path, the chunk's own top min(pool, bn) on the fallback.
+    gathered = jnp.where(over_chunk, m * min(pool, bn), m * cap)
     stats = QueryStats(
         overflow_chunks=jnp.sum(over_chunk, dtype=jnp.int32),
         overflow_rows=jnp.sum(over_rows, dtype=jnp.int32),
+        rerank_rows=jnp.sum(gathered, dtype=jnp.int32),
+        rerank_live=jnp.sum(live_rows, dtype=jnp.int32),
         chunks=n_blocks,
         rows=m,
     )
@@ -2080,8 +2124,8 @@ def _lint_build_budget_bytes() -> int:
     n_pad = -(-n // bn) * bn
     codebooks = 2 * ns
     elems = max(
-        codebooks * n_pad * h_max,  # the blocked data views (O(n*d))
-        n * d,  # the permuted input itself
+        codebooks * h_max * n_pad,  # the lane layout and its blocks (O(n*d))
+        n * d,  # the input itself
         2 * codebooks * bn * max(sqrt_k, h_max),  # per-chunk dist + one-hot
         ns * sqrt_k * sqrt_k,  # cell_counts
     )

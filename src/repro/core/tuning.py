@@ -517,11 +517,14 @@ def autotune_build_block_n(
 ) -> int:
     """Chunk size for the streaming index build (chunked/minibatch Lloyd).
 
-    Each K-means step materialises per chunk a ``(2Ns, block_n, sqrtK)``
-    distance block and the ``(2Ns, block_n, h_max)`` half-space view; the
-    largest 512-multiple whose sum fits the backend's fast memory keeps
-    the assign/stats scan cache-resident without shrinking chunks (and
-    therefore multiplying scan steps) more than the hardware requires.
+    Each K-means step holds per chunk the ``(2Ns, h_max, block_n)`` slice
+    of the points-on-lanes half-subspace layout and ``(sqrtK, block_n)``
+    distances for each of the ``2Ns`` codebooks; the largest 512-multiple
+    whose sum fits the backend's fast memory keeps the assign/stats pass
+    cache-resident without shrinking chunks (and therefore multiplying
+    steps) more than the hardware requires.  On the TPU the kernels cap
+    their own block further to their VMEM budget
+    (:func:`repro.kernels.kmeans_assign.ops.lane_block`).
     """
     if min(n, d, sqrt_k, n_subspaces) < 1:
         raise ValueError(

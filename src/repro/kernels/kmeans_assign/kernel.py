@@ -21,6 +21,9 @@ Three entry points share that structure (grid ``(n/bn,)``):
 * :func:`kmeans_pair_assign_hist_kernel` — final assignment plus the IMI
   occupancy histogram of SuCo's paired half-subspaces.
 
+:func:`halves_to_lanes_kernel` writes that ``(B, s, n)`` layout from the
+row-major points once per index build.
+
 Distances are the matmul identity ``|x|^2 + |c|^2 - 2 c.x`` with the
 cross term at ``Precision.HIGHEST`` (full f32 on the MXU), the same
 formula as the jnp oracle.
@@ -279,3 +282,61 @@ def kmeans_stats_kernel(
         out_shape=[jax.ShapeDtypeStruct((b, n), jnp.int32)] + stats_shapes,
         interpret=interpret,
     )(xt, centroids, weights)
+
+
+def _halves_kernel(x_ref, o_ref, *, starts, widths, n, columns):
+    """One block of points -> ``o (B, s, bt)``: the ``widths[b]`` dims from
+    ``starts[b]`` of every point on lanes, zero rows after them, zero
+    columns for points at or past ``n``.  The block is ``x (bt, d)`` rows,
+    transposed here, or with ``columns`` already ``x^T (d, bt)``."""
+    vt = x_ref[...] if columns else x_ref[...].T  # (d, bt)
+    bt = vt.shape[1]
+    if n is not None:  # the last blocks run past the points
+        col = pl.program_id(0) * bt + jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1)
+        vt = jnp.where(col < n, vt, jnp.zeros((), vt.dtype))
+    s = o_ref.shape[1]
+    for b, (a, w) in enumerate(zip(starts, widths)):
+        o_ref[b, :w, :] = vt[a:a + w]
+        if w < s:
+            o_ref[b, w:, :] = jnp.zeros((s - w, bt), o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("starts", "widths", "s", "n_pad", "bt", "columns", "interpret"),
+)
+def halves_to_lanes_kernel(
+    x: jax.Array,
+    *,
+    starts: tuple[int, ...],
+    widths: tuple[int, ...],
+    s: int,
+    n_pad: int,
+    bt: int,
+    columns: bool = False,
+    interpret: bool = False,
+) -> jax.Array:
+    """``x (n, d)`` rows, or with ``columns`` ``x^T (d, n)`` -> ``(B, s,
+    n_pad)``: codebook ``b`` holds dims ``starts[b] : starts[b] + widths[b]``
+    of every point on lanes (zero rows up to ``s``, zero columns from
+    ``n``) — the layout the kernels above stream, written in one pass over
+    blocks of ``bt`` points (transposed in VMEM unless ``columns``).
+    Caller picks ``bt % 128 == 0`` dividing ``n_pad``."""
+    n = x.shape[1] if columns else x.shape[0]
+    d = x.shape[0] if columns else x.shape[1]
+    last = (n - 1) // bt  # blocks past the points re-read the last one
+    if columns:
+        in_spec = pl.BlockSpec((d, bt), lambda j: (0, jnp.minimum(j, last)))
+    else:
+        in_spec = pl.BlockSpec((bt, d), lambda j: (jnp.minimum(j, last), 0))
+    return pl.pallas_call(
+        functools.partial(
+            _halves_kernel, starts=starts, widths=widths,
+            n=None if n == n_pad else n, columns=columns,
+        ),
+        grid=(n_pad // bt,),
+        in_specs=[in_spec],
+        out_specs=pl.BlockSpec((len(starts), s, bt), lambda j: (0, 0, j)),
+        out_shape=jax.ShapeDtypeStruct((len(starts), s, n_pad), x.dtype),
+        interpret=interpret,
+    )(x)

@@ -159,6 +159,8 @@ class StepRecord:
     # where the executable reports none (dense, streaming, a proxy engine).
     overflow_chunks: int | None = None  # chunks that took the fallback
     overflow_rows: int | None = None  # (row, chunk) pairs over the cap
+    rerank_rows: int | None = None  # rows of x gathered to rerank
+    rerank_live: int | None = None  # of them, rows of a live candidate
     chunks: int | None = None  # chunks the scan ran
     rows: int | None = None  # rows of the padded batch
 
@@ -878,9 +880,11 @@ def _counters(stats: QueryStats | None) -> dict:
     """:class:`StepRecord` counter fields of a finished batch's stats."""
     if stats is None:
         return {}
+    names = ("overflow_chunks", "overflow_rows", "rerank_rows", "rerank_live")
+    # one host copy for the four scalars
+    got = jax.device_get([getattr(stats, f) for f in names])  # jaxlint: sync-ok — after serve.wait
     return dict(
-        overflow_chunks=int(stats.overflow_chunks),
-        overflow_rows=int(stats.overflow_rows),
+        {f: int(v) for f, v in zip(names, got)},
         chunks=stats.chunks,
         rows=stats.rows,
     )

@@ -66,6 +66,7 @@ def test_registry_covers_the_serving_surface():
         "kernels.kmeans_assign.batched",
         "kernels.kmeans_assign.stats",
         "kernels.kmeans_assign.oracle",
+        "kernels.kmeans_assign.halves",
         "kernels.pairwise_l2.kernel",
         "kernels.pairwise_l2.oracle",
     }

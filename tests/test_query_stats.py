@@ -74,6 +74,33 @@ def _host_overflow(x, index, q, tiles) -> np.ndarray:
     return np.stack(over)
 
 
+def _host_rerank(x, index, q, tiles) -> tuple[int, int]:
+    """``(rerank_rows, rerank_live)`` replayed on the host from the dense
+    scores: a chunk with no overflowing row gathers ``cap`` slots per row,
+    live where a column beats the carried pool minimum; a chunk that
+    overflows gathers its own top ``min(pool, bn)`` per row, live where the
+    pick is one of the chunk's real columns."""
+    n, m = x.shape[0], q.shape[0]
+    scores = np.asarray(suco_scores(index, q, sub.collision_count(n, ALPHA)))
+    pool = candidate_pool_size(n, K, BETA)
+    bn = min(tiles.block_n, n)
+    cap = min(tiles.survivor_cap, bn)
+    pool_s = np.full((m, pool), -1, np.int64)
+    rows = live = 0
+    for b in range(-(-n // bn)):
+        blk = scores[:, b * bn:(b + 1) * bn].astype(np.int64)
+        surv = (blk > pool_s.min(axis=1, keepdims=True)).sum(axis=1)
+        if (surv > cap).any():
+            rows += m * min(pool, bn)
+            live += m * min(pool, bn, blk.shape[1])
+        else:
+            rows += m * cap
+            live += int(surv.sum())
+        both = np.concatenate([pool_s, blk], axis=1)
+        pool_s = -np.sort(-both, axis=1)[:, :pool]
+    return rows, live
+
+
 @pytest.mark.parametrize("tiles", [
     TileConfig(block_n=512, survivor_cap=64),
     TileConfig(block_n=333, survivor_cap=40),  # does not divide n
@@ -88,6 +115,24 @@ def test_fused_counters_match_host_recount(problem, tiles):
     assert stats.chunks == want.shape[0] and stats.rows == M
     assert int(stats.overflow_chunks) == int(want.any(axis=1).sum())
     assert int(stats.overflow_rows) == int(want.sum())
+
+
+@pytest.mark.parametrize("tiles", [
+    TileConfig(block_n=512, survivor_cap=64),
+    TileConfig(block_n=333, survivor_cap=40),  # does not divide n
+    TileConfig(block_n=1000, survivor_cap=1),  # every chunk overflows
+    TileConfig(block_n=256, survivor_cap=200),
+    # the last chunk holds 4 columns: its fallback picks past the chunk's end
+    TileConfig(block_n=333, survivor_cap=1),
+])
+def test_rerank_counters_match_host_replay(problem, tiles):
+    ds, x, index = problem
+    q = jnp.asarray(ds.queries)
+    _, stats = _fused(x, index, q, tiles)
+    rows, live = _host_rerank(x, index, q, tiles)
+    assert int(stats.rerank_rows) == rows
+    assert int(stats.rerank_live) == live
+    assert 0 < int(stats.rerank_live) <= int(stats.rerank_rows)
 
 
 def test_no_overflow_when_cap_covers_the_chunk(problem):
@@ -166,6 +211,7 @@ def test_step_records_carry_counters_and_requests_link_them(problem, server_cls)
     for rec in server.steps:
         assert rec.chunks == 8 and rec.rows == 4  # ceil(4000 / 512), bucket 4
         assert 1 <= rec.overflow_chunks <= rec.overflow_rows <= 4 * rec.overflow_chunks
+        assert 0 < rec.rerank_live <= rec.rerank_rows
     for r in done:
         assert r.done and any(r.step is s for s in server.steps)
     assert sorted(r.step.n_requests for r in done) == [2] * 2 + [4] * 8
@@ -174,6 +220,8 @@ def test_step_records_carry_counters_and_requests_link_them(problem, server_cls)
     _, want = engine.query_with_stats(np.stack([r.query for r in batch0]), K)
     assert server.steps[0].overflow_rows == int(want.overflow_rows)
     assert server.steps[0].overflow_chunks == int(want.overflow_chunks)
+    assert server.steps[0].rerank_rows == int(want.rerank_rows)
+    assert server.steps[0].rerank_live == int(want.rerank_live)
 
 
 @pytest.mark.parametrize("server_cls", [AnnServer, AsyncAnnServer])
@@ -185,6 +233,7 @@ def test_dense_steps_carry_no_counters(problem, server_cls):
     done = server.run_until_drained()
     assert all(r.step is not None and r.step.chunks is None for r in done)
     assert all(s.overflow_chunks is None and s.rows is None for s in server.steps)
+    assert all(s.rerank_rows is None and s.rerank_live is None for s in server.steps)
 
 
 def _trace_event_names(log_dir: str) -> set[str]:
