@@ -918,16 +918,23 @@ class QueryStats:
     fallback chunk, over the padded batch of ``m`` rows.  ``rerank_live``:
     those of them that held a live candidate (a survivor slot below the
     row's survivor count, a fallback pick that is a real column); the rest
-    were gathered for a dead slot.  ``chunks`` and ``rows`` are the scan's
-    static extent.
+    were gathered for a dead slot.  ``slot_windows``: the 128-slot windows
+    the score kernel's survivor write built a one-hot for, and
+    ``slot_writes``: the (row, column block) pairs that wrote any slot,
+    over every chunk; each such pair once cost a one-hot over all
+    ``slot_cap / 128`` windows.  ``chunks`` and ``rows`` are the scan's
+    static extent, ``slot_cap`` the kernel's lane-rounded survivor cap.
     """
 
     overflow_chunks: jax.Array  # () int32
     overflow_rows: jax.Array  # () int32
     rerank_rows: jax.Array  # () int32
     rerank_live: jax.Array  # () int32
+    slot_windows: jax.Array  # () int32
+    slot_writes: jax.Array  # () int32
     chunks: int = dataclasses.field(metadata=dict(static=True))
     rows: int = dataclasses.field(metadata=dict(static=True))
+    slot_cap: int = dataclasses.field(metadata=dict(static=True))
 
 
 class EngineResult(NamedTuple):
@@ -1067,8 +1074,8 @@ def suco_query_fused(
        having strictly smaller ids under the streaming invariant), and
        the survivors come back already compacted into a
        ``survivor_cap``-wide buffer in ascending-id order — in-kernel
-       prefix count + one-hot slot writes while the score tile is resident, so
-       no sort, scatter, or second pass ever touches the
+       prefix count + windowed one-hot slot writes while the score tile
+       is resident, so no sort, scatter, or second pass ever touches the
        ``(m, block_n)`` block and the merge's lexicographic tie-break is
        preserved bit-for-bit.  (The jnp oracle — the production CPU
        path — runs the identical compaction as a binary search on the
@@ -1165,10 +1172,12 @@ def _fused_query(
         thr = pool_s[:, -1]  # pool sorted desc -> last col is the minimum
         limit = jnp.minimum(n - blk * bn, bn)  # valid columns this chunk
         with scope("score"):
-            s, surv_c, surv_s, total = sc_scores_cells_prefilter_compact(
-                ranks, cuts, cells_b, thr, limit, keep_b,
-                cap=cap, bm=tiles.bm, bn=tiles.bn, impl=score_impl,
-            )  # (m, bn), (m, cap), (m, cap), (m) — all int32, s pre-masked
+            s, surv_c, surv_s, total, windows, writes = (
+                sc_scores_cells_prefilter_compact(
+                    ranks, cuts, cells_b, thr, limit, keep_b,
+                    cap=cap, bm=tiles.bm, bn=tiles.bn, impl=score_impl,
+                )
+            )  # (m, bn), (m, cap), (m, cap), (m,) x 3 — int32, s pre-masked
         gids = blk * bn + cols
         col_ok = cols < limit
         if keep_b is not None:
@@ -1235,16 +1244,21 @@ def _fused_query(
         over = total > cap  # (m,) rows whose survivors overflow the cap
         overflow = jnp.any(over)
         merged, live_rows = jax.lax.cond(overflow, full_merge, pruned_merge, None)
-        return merged, (overflow, jnp.sum(over, dtype=jnp.int32), live_rows)
+        counts = (
+            overflow, jnp.sum(over, dtype=jnp.int32), live_rows,
+            jnp.sum(windows, dtype=jnp.int32), jnp.sum(writes, dtype=jnp.int32),
+        )
+        return merged, counts
 
     init = (
         jnp.full((m, pool), -1, jnp.int32),
         jnp.full((m, pool), inf, dist_dtype),
         jnp.full((m, pool), int_max, jnp.int32),
     )
-    (pool_s, pool_d, pool_i), (over_chunk, over_rows, live_rows) = jax.lax.scan(
+    (pool_s, pool_d, pool_i), counts = jax.lax.scan(
         step, init, (jnp.arange(n_blocks, dtype=jnp.int32), cells, keep_blocks)
     )
+    over_chunk, over_rows, live_rows, windows, writes = counts
     # Final selection == rerank_candidates' top_k on the carried pool:
     # ascending distance, ties to the earlier (score desc, id asc) slot.
     with scope("select"):
@@ -1262,8 +1276,11 @@ def _fused_query(
         overflow_rows=jnp.sum(over_rows, dtype=jnp.int32),
         rerank_rows=jnp.sum(gathered, dtype=jnp.int32),
         rerank_live=jnp.sum(live_rows, dtype=jnp.int32),
+        slot_windows=jnp.sum(windows, dtype=jnp.int32),
+        slot_writes=jnp.sum(writes, dtype=jnp.int32),
         chunks=n_blocks,
         rows=m,
+        slot_cap=-(-cap // 128) * 128,  # the kernel rounds cap up to lanes
     )
     return res, stats
 

@@ -54,7 +54,7 @@ def cells_vmem_bytes(
 
     The double-buffered activation block dominates; the ``(bm, bn)`` tiles
     (score block, slot scratch, collision accumulator, prefix-count
-    temporaries) and the slot one-hot add the rest, plus 2 MiB for the
+    temporaries) and the slot one-hots add the rest, plus 2 MiB for the
     cell one-hot and Mosaic's own scratch.  Calibrated against the smallest
     ``vmem_limit_bytes`` the compact kernel compiles under for a described
     v5e at K=2560: 2, 2, 9, 7, 9, 10, 3, 14, 12 and 9 MiB at ``(Ns, bm, bn,
@@ -62,6 +62,11 @@ def cells_vmem_bytes(
     (8, 64, 512, 256), (8, 64, 2048, 1024), (8, 64, 2048, 2048),
     (8, 8, 2048, 2048), (8, 128, 1024, 256), (16, 64, 1024, 512),
     (8, 64, 512, 3328).  The model is 1.4x to 2.4x above each of them.
+
+    The slot term was calibrated on a slot write that built a ``(cap, bn)``
+    one-hot per row; the write now builds ``(128, bn)`` windows, so the
+    term overstates it.  It is kept so that :func:`_fit_kernel_vmem`'s
+    tile plan (``repro.core.tuning``) stays the one it was.
     """
     act = n_sub * bm * _round_up(k_cells, _LANE) * 4
     tiles = 6 * bm * bn * 4
@@ -148,7 +153,8 @@ def _cells_prefilter_kernel(act_ref, thr_ref, cell_ref, score_ref, keep_ref, *, 
 
 def _cells_prefilter_compact_kernel(
     act_ref, thr_ref, limit_ref, cell_ref,
-    score_ref, svcol_ref, svscore_ref, cnt_ref, dest_ref,
+    score_ref, svcol_ref, svscore_ref, cnt_ref, win_ref, wrt_ref,
+    dest_ref, col_win_ref, score_win_ref,
     *, n_sub: int, bn: int, cap: int,
 ):
     j = pl.program_id(1)  # column-block index (sequential -> cnt accumulates)
@@ -156,9 +162,11 @@ def _cells_prefilter_compact_kernel(
 
     @pl.when((j == 0) & (i == 0))
     def _init_survivors():
-        svcol_ref[...] = jnp.zeros_like(svcol_ref)
-        svscore_ref[...] = jnp.full_like(svscore_ref, -1)
+        col_win_ref[...] = jnp.zeros_like(col_win_ref)
+        score_win_ref[...] = jnp.full_like(score_win_ref, -1)
         cnt_ref[...] = jnp.zeros_like(cnt_ref)
+        win_ref[...] = jnp.zeros_like(win_ref)
+        wrt_ref[...] = jnp.zeros_like(wrt_ref)
 
     @pl.when(i == 0)
     def _init_scores():
@@ -193,22 +201,31 @@ def _cells_prefilter_compact_kernel(
             dest = incl - 1  # slot of each kept column
             dest_ref[:, g:g + _LANE] = jnp.where(kg & (dest < cap), dest, -1)
             run = incl[:, _LANE - 1:]
-        cnt_ref[...] = run
 
-        # Slot writes, one query row at a time: a (cap, bn) one-hot of the
-        # row's destinations contracts its (score + 1, column) values into
-        # the slots on the MXU.  Every operand is an integer <= 256, exact
-        # in bf16 — the column is split into its low and high bytes
-        # (block_n <= 2^16) — and each slot is written at most once, so
-        # the += against the -1 / 0 initialisation is exact.
+        # Slot writes, one query row at a time, through 128-slot windows:
+        # a row's survivors of this block take the consecutive slots from
+        # its count before the block (still in ``cnt_ref``) to its largest
+        # destination, so only the windows between them are written — one
+        # or two on a warm pool, at most bn/128 + 1.  Per window a
+        # (128, bn) one-hot of the row's destinations contracts its
+        # (score + 1, column) values into those slots on the MXU.  Every
+        # operand is an integer <= 256, exact in bf16 — the column is
+        # split into its low and high bytes (block_n <= 2^16) — and each
+        # slot is written at most once, so the += against the -1 / 0
+        # initialisation is exact.  The windows live in ``(cap/128, bm,
+        # 128)`` scratch, indexed on the leading axis: Mosaic refuses a
+        # dynamic lane offset into the ``(bm, cap)`` outputs.
+        # ``win_ref``/``wrt_ref`` count the windows written and the rows
+        # that wrote.
         colrow = j * bn + jax.lax.broadcasted_iota(jnp.int32, (1, bn), 1)
         sub = jax.lax.broadcasted_iota(jnp.int32, (_SUBLANE, bn), 0)
-        slots = jax.lax.broadcasted_iota(jnp.int32, (cap, bn), 0)
+        slots = jax.lax.broadcasted_iota(jnp.int32, (_LANE, bn), 0)
 
         def write_row(r, carry):
             d_r = dest_ref[pl.ds(r, 1), :]  # (1, bn), -1 = no write
+            last = jnp.max(d_r)
 
-            @pl.when(jnp.max(d_r) >= 0)
+            @pl.when(last >= 0)
             def _write():
                 s_r = score_ref[pl.ds(r, 1), :]
                 vals = jnp.where(
@@ -216,17 +233,33 @@ def _cells_prefilter_compact_kernel(
                     jnp.where(sub == 1, colrow & 255,
                               jnp.where(sub == 2, colrow >> 8, 0)),
                 ).astype(jnp.bfloat16)  # (8, bn)
-                onehot = (slots == d_r).astype(jnp.bfloat16)  # (cap, bn)
-                out = jax.lax.dot_general(
-                    vals, onehot, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                ).astype(jnp.int32)  # (8, cap)
-                svscore_ref[pl.ds(r, 1), :] += out[0:1]
-                svcol_ref[pl.ds(r, 1), :] += out[1:2] + 256 * out[2:3]
+                first = jnp.max(cnt_ref[pl.ds(r, 1), :]) // _LANE
+                stop = last // _LANE + 1
+
+                def write_window(w, c):
+                    onehot = (slots == d_r - w * _LANE).astype(jnp.bfloat16)
+                    out = jax.lax.dot_general(
+                        vals, onehot, (((1,), (1,)), ((), ())),
+                        preferred_element_type=jnp.float32,
+                    ).astype(jnp.int32)  # (8, 128)
+                    score_win_ref[w, pl.ds(r, 1), :] += out[0:1]
+                    col_win_ref[w, pl.ds(r, 1), :] += out[1:2] + 256 * out[2:3]
+                    return c
+
+                jax.lax.fori_loop(first, stop, write_window, 0)
+                win_ref[pl.ds(r, 1), :] += jnp.full((1, 1), stop - first, jnp.int32)
+                wrt_ref[pl.ds(r, 1), :] += 1
 
             return carry
 
         jax.lax.fori_loop(0, bm, write_row, 0)
+        cnt_ref[...] = run
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _emit_survivors():
+            for w in range(cap // _LANE):
+                svscore_ref[:, w * _LANE:(w + 1) * _LANE] = score_win_ref[w]
+                svcol_ref[:, w * _LANE:(w + 1) * _LANE] = col_win_ref[w]
 
 
 @functools.partial(jax.jit, static_argnames=("bm", "bn", "cap", "interpret"))
@@ -240,7 +273,7 @@ def sc_score_cells_prefilter_compact_kernel(
     bn: int = 512,
     cap: int = 256,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, ...]:
     """Fused chunk stage with in-kernel survivor compaction.
 
     :func:`sc_score_cells_prefilter_kernel` taken one step further: instead
@@ -257,7 +290,11 @@ def sc_score_cells_prefilter_compact_kernel(
     * ``surv_scores (m, cap)`` — its score (-1 for empty slots);
     * ``count (m, 1)`` — the *true* survivor count, which may exceed
       ``cap`` (overflow slots are dropped; the caller detects
-      ``count > cap`` and falls back to an exact full merge).
+      ``count > cap`` and falls back to an exact full merge);
+    * ``slot_windows (m, 1)`` — the 128-slot windows the slot write built
+      a one-hot for, over the chunk's column blocks;
+    * ``slot_writes (m, 1)`` — the column blocks in which the row wrote
+      any slot (each would have cost a ``(cap, bn)`` one-hot whole).
 
     The survivor tiles revisit across the whole (column-block, subspace)
     grid sweep, so the running count threads destination slots across
@@ -284,14 +321,22 @@ def sc_score_cells_prefilter_compact_kernel(
             pl.BlockSpec((bm, cap), lambda i, j, k: (i, 0)),
             pl.BlockSpec((bm, cap), lambda i, j, k: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
+            pl.BlockSpec((bm, 1), lambda i, j, k: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((m, bc), jnp.int32),
             jax.ShapeDtypeStruct((m, cap), jnp.int32),
             jax.ShapeDtypeStruct((m, cap), jnp.int32),
             jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m, 1), jnp.int32),
+            jax.ShapeDtypeStruct((m, 1), jnp.int32),
         ],
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
+        scratch_shapes=[
+            pltpu.VMEM((bm, bn), jnp.int32),
+            pltpu.VMEM((cap // _LANE, bm, _LANE), jnp.int32),
+            pltpu.VMEM((cap // _LANE, bm, _LANE), jnp.int32),
+        ],
         compiler_params=_vmem_params(cells_vmem_bytes(n_sub, bm, bn, kp, cap)),
         interpret=interpret,
     )(act, thr, limit, cells)

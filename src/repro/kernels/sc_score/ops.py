@@ -18,6 +18,7 @@ from repro.kernels.sc_score.ref import (
     sc_score_cells_prefilter_ref,
     sc_score_cells_ref,
     sc_score_ref,
+    slot_window_counts,
 )
 
 
@@ -159,9 +160,10 @@ def sc_scores_cells_prefilter_compact(
     bn: int = 512,
     impl: str = "auto",
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
+) -> tuple[jax.Array, ...]:
     """One-launch chunk stage for the single-pass engine:
-    ``-> (scores, surv_cols, surv_scores, count)``.
+    ``-> (scores, surv_cols, surv_scores, count, slot_windows,
+    slot_writes)``.
 
     :func:`sc_scores_cells_prefilter` plus the survivor compaction the
     fused query used to run as a host-graph cumsum/searchsorted/gather —
@@ -172,6 +174,11 @@ def sc_scores_cells_prefilter_compact(
     scores (``(m, cap)``, ascending-column order, 0 / -1 in empty slots),
     and the *true* per-query survivor count (``(m,)``, may exceed ``cap``
     — the caller's exact-fallback signal; overflowed slots are dropped).
+    ``slot_windows`` and ``slot_writes`` (``(m,)``) count the kernel's
+    slot write: the 128-slot windows it wrote and the column blocks in
+    which the row wrote any slot, at the kernel's ``bn`` and its
+    lane-rounded ``cap``; the jnp path replays them from its keep mask
+    (:func:`repro.kernels.sc_score.ref.slot_window_counts`).
 
     ``keep_cols`` (optional ``(bc,) bool``, default all-live) is the
     live-mutation tombstone mask: False columns are deleted points.  The
@@ -189,17 +196,22 @@ def sc_scores_cells_prefilter_compact(
     they never survive, and ``cap`` is rounded up to a lane multiple for
     the kernel then sliced back.
     """
+    m, bc = ranks.shape[1], cells.shape[1]
+    capp = _round_up(cap, 128)
     if impl == "jnp" or (impl == "auto" and jax.default_backend() != "tpu"):
-        return sc_score_cells_prefilter_compact_ref(
+        out = sc_score_cells_prefilter_compact_ref(
             ranks, cuts, cells, thr, limit, keep_cols, cap=cap
         )
-    m, bc = ranks.shape[1], cells.shape[1]
+        bn_ = min(bn, _round_up(bc, 128))  # the kernel's column block
+        keep = out[0] > thr[:, None]
+        return (*out, *slot_window_counts(keep, bn=bn_, cap=capp))
     act, cellp, bm_, bn_ = _pad_cells_inputs(ranks, cuts, cells, bm=bm, bn=bn)
-    capp = _round_up(cap, 128)
     limp = jnp.reshape(limit, (1, 1)).astype(jnp.int32)
-    out_s, out_c, out_ss, out_n = sc_score_cells_prefilter_compact_kernel(
-        act, _pad_thr(thr, act.shape[1]), limp, cellp, bm=bm_, bn=bn_,
-        cap=capp, interpret=interpret,
+    out_s, out_c, out_ss, out_n, out_w, out_r = (
+        sc_score_cells_prefilter_compact_kernel(
+            act, _pad_thr(thr, act.shape[1]), limp, cellp, bm=bm_, bn=bn_,
+            cap=capp, interpret=interpret,
+        )
     )
     out_s = out_s[:m, :bc]
     out_c, out_ss, out_n = out_c[:m, :cap], out_ss[:m, :cap], out_n[:m, 0]
@@ -207,7 +219,7 @@ def sc_scores_cells_prefilter_compact(
         out_s = jnp.where(keep_cols[None, :], out_s, -1)
         dead_slot = jnp.logical_not(jnp.take(keep_cols, out_c))
         out_ss = jnp.where(dead_slot, -1, out_ss)
-    return out_s, out_c, out_ss, out_n
+    return out_s, out_c, out_ss, out_n, out_w[:m, 0], out_r[:m, 0]
 
 
 __all__ = [
@@ -349,6 +361,8 @@ def jaxlint_entries():
                     5: ((0, 8), (1, 128)),  # surv_cols (bm, cap)
                     6: ((0, 8), (1, 128)),  # surv_scores (bm, cap)
                     7: ((0, 8),),  # count (bm, 1)
+                    8: ((0, 8),),  # slot_windows (bm, 1)
+                    9: ((0, 8),),  # slot_writes (bm, 1)
                 },
             },
             note="one-launch chunk stage: scores + in-kernel survivor compaction",

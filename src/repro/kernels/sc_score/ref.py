@@ -79,6 +79,34 @@ def sc_score_cells_prefilter_compact_ref(
     return s, surv_cols, surv_scores, total
 
 
+def slot_window_counts(
+    keep: jax.Array, *, bn: int, cap: int
+) -> tuple[jax.Array, jax.Array]:
+    """The compact kernel's slot-write counters, replayed from its keep mask.
+
+    ``keep (m, bc)`` is the prefilter's survivors mask, walked in
+    ``bn``-wide column blocks against ``cap`` slots.  In block b a row
+    whose count before the block is ``c0`` and after it ``c1`` writes the
+    slots ``[c0, min(c1, cap))``: 128-slot windows ``c0 // 128`` through
+    ``(min(c1, cap) - 1) // 128``, or none.  Returns
+    ``(slot_windows (m,), slot_writes (m,))`` int32, summed over the
+    blocks: the windows written and the blocks that wrote any slot.
+    """
+    m, bc = keep.shape
+    nb = -(-bc // bn)
+    k = jnp.pad(keep.astype(jnp.int32), ((0, 0), (0, nb * bn - bc)))
+    per_block = jnp.sum(k.reshape(m, nb, bn), axis=2)  # (m, nb)
+    c1 = jnp.cumsum(per_block, axis=1)
+    c0 = c1 - per_block
+    last = jnp.minimum(c1, cap) - 1
+    wrote = last >= c0
+    wins = jnp.where(wrote, last // 128 - c0 // 128 + 1, 0)
+    return (
+        jnp.sum(wins, axis=1, dtype=jnp.int32),
+        jnp.sum(wrote, axis=1, dtype=jnp.int32),
+    )
+
+
 def sc_score_ref(qs: jax.Array, xs: jax.Array, tau: jax.Array) -> jax.Array:
     """``qs: (Ns,m,s), xs: (Ns,n,s), tau: (Ns,m) -> (m,n)`` int32 scores."""
     qf, xf = qs.astype(jnp.float32), xs.astype(jnp.float32)

@@ -161,8 +161,11 @@ class StepRecord:
     overflow_rows: int | None = None  # (row, chunk) pairs over the cap
     rerank_rows: int | None = None  # rows of x gathered to rerank
     rerank_live: int | None = None  # of them, rows of a live candidate
+    slot_windows: int | None = None  # 128-slot windows the score kernel wrote
+    slot_writes: int | None = None  # (row, column block) pairs that wrote
     chunks: int | None = None  # chunks the scan ran
     rows: int | None = None  # rows of the padded batch
+    slot_cap: int | None = None  # the score kernel's lane-rounded cap
 
 
 @dataclasses.dataclass
@@ -880,13 +883,17 @@ def _counters(stats: QueryStats | None) -> dict:
     """:class:`StepRecord` counter fields of a finished batch's stats."""
     if stats is None:
         return {}
-    names = ("overflow_chunks", "overflow_rows", "rerank_rows", "rerank_live")
-    # one host copy for the four scalars
+    names = (
+        "overflow_chunks", "overflow_rows", "rerank_rows", "rerank_live",
+        "slot_windows", "slot_writes",
+    )
+    # one host copy for the scalars
     got = jax.device_get([getattr(stats, f) for f in names])  # jaxlint: sync-ok — after serve.wait
     return dict(
         {f: int(v) for f, v in zip(names, got)},
         chunks=stats.chunks,
         rows=stats.rows,
+        slot_cap=stats.slot_cap,
     )
 
 

@@ -427,6 +427,16 @@ def test_autotune_tiles_scales_with_memory():
         autotune_tiles(0, 8, 1, 10)
 
 
+@pytest.mark.parametrize("d", [128, 960])
+def test_autotune_tiles_tpu_plan_at_one_million(d):
+    """The chip's plan for the two online cells (1M points, batch 64, a
+    20,000-candidate pool): the score kernel's VMEM model keeps its slot
+    term, so the windowed slot write leaves these tiles where they were."""
+    assert autotune_tiles(1_000_000, d, 64, 20_000, backend="tpu") == TileConfig(
+        block_n=20_480, bm=64, bn=1_024, survivor_cap=3_328
+    )
+
+
 def test_autotune_build_block_n_bounds():
     bn = autotune_build_block_n(100_000, 32, sqrt_k=50, n_subspaces=8)
     assert bn % 512 == 0 and 512 <= bn <= 1 << 16

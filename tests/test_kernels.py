@@ -538,6 +538,133 @@ def test_sc_score_compact_kernel_edge_cases(case, ns, m, k_cells, bc, cap, bm, b
         assert g == (w if count <= cap else w[: len(g)])
 
 
+def _keep_pattern_inputs(keep):
+    """Compact-kernel inputs whose survivors are exactly ``keep (m, bc)``:
+    one subspace, every column its own cell, a row's cell active where it
+    keeps the column, and a pool minimum of 0 — a kept column scores 1."""
+    m, bc = keep.shape
+    ranks = jnp.asarray(np.where(keep, 0, 1)[None], jnp.int32)  # (1, m, bc)
+    cuts = jnp.zeros((1, m), jnp.int32)
+    cells = jnp.arange(bc, dtype=jnp.int32)[None]
+    thr = jnp.zeros((m,), jnp.int32)
+    return ranks, cuts, cells, thr
+
+
+def _window_recount(keep, bn, cap):
+    """``(slot_windows, slot_writes)`` per row, counted block by block in
+    numpy: a block that moves a row's count from c0 to c1 writes slots
+    [c0, min(c1, cap)), the 128-slot windows they fall in."""
+    m, bc = keep.shape
+    wins, writes = np.zeros(m, np.int64), np.zeros(m, np.int64)
+    for r in range(m):
+        c0 = 0
+        for b0 in range(0, bc, bn):
+            c1 = c0 + int(keep[r, b0:b0 + bn].sum())
+            hi = min(c1, cap)
+            if hi > c0:
+                wins[r] += (hi - 1) // 128 - c0 // 128 + 1
+                writes[r] += 1
+            c0 = c1
+    return wins, writes
+
+
+def _block_keeps(rng, m, bc, bn, per_block):
+    """``(m, bc)`` bool: row r keeps ``per_block[r]`` random columns of
+    every ``bn``-wide block (row 0 keeps nothing)."""
+    keep = np.zeros((m, bc), bool)
+    for r in range(1, m):
+        for b0 in range(0, bc, bn):
+            width = min(bn, bc - b0)
+            take = rng.choice(width, min(per_block[r], width), replace=False)
+            keep[r, b0 + take] = True
+    return keep
+
+
+@pytest.mark.parametrize(
+    "case, m, bc, cap, bn, per_block",
+    [
+        # 100 a block: slots 0-99, 100-199, 200-299 cross 128 and 256
+        ("straddle", 9, 768, 384, 256, (0, 100, 27, 128, 1, 129, 100, 64, 3)),
+        # cap reached mid-block: 200 a block fills 384 slots in block 1
+        ("cap_mid_block", 8, 768, 384, 256, (0, 200, 200, 150, 255, 2, 0, 77)),
+        # 300 a 512-wide block: a row writes 3 windows at once, then stops
+        ("wide_blocks", 8, 1024, 640, 512, (0, 300, 512, 129, 1, 500, 0, 255)),
+    ],
+)
+def test_compact_kernel_window_write_exact(case, m, bc, cap, bn, per_block):
+    """The windowed slot write (interpret mode) against the oracle, bit
+    for bit, on survivor patterns chosen per row: runs of slots that cross
+    128-slot window edges, rows that fill the cap partway through a block,
+    more than 128 survivors of one row in one block, and a row that keeps
+    nothing.  The kernel's window counters equal a numpy recount."""
+    from repro.kernels.sc_score.ops import sc_scores_cells_prefilter_compact
+    from repro.kernels.sc_score.ref import sc_score_cells_prefilter_compact_ref
+
+    keep = _block_keeps(np.random.default_rng(len(case)), m, bc, bn, per_block)
+    args = (*_keep_pattern_inputs(keep), jnp.int32(bc))
+    got = sc_scores_cells_prefilter_compact(
+        *args, cap=cap, bm=8, bn=bn, impl="pallas", interpret=True
+    )
+    want = sc_score_cells_prefilter_compact_ref(*args, cap=cap)
+    np.testing.assert_array_equal(np.asarray(want[3]), keep.sum(axis=1))
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    wins, writes = _window_recount(keep, bn, cap)
+    np.testing.assert_array_equal(np.asarray(got[4]), wins)
+    np.testing.assert_array_equal(np.asarray(got[5]), writes)
+    assert wins[0] == writes[0] == 0
+
+
+def test_compact_kernel_keeps_every_column_when_thr_is_minus_one():
+    """A cold pool (thr = -1): every valid column survives, hundreds of
+    them a block for each row, and the columns past ``limit`` score -1 and
+    take no slot."""
+    from repro.kernels.sc_score.ops import sc_scores_cells_prefilter_compact
+    from repro.kernels.sc_score.ref import sc_score_cells_prefilter_compact_ref
+
+    rng = np.random.default_rng(5)
+    ns, m, k_cells, bc, cap, bn, limit = 3, 8, 40, 1024, 768, 512, 1000
+    ranks, cuts = _ranks_cuts(rng, ns, m, k_cells)
+    cells = jnp.asarray(rng.integers(0, k_cells, size=(ns, bc)), jnp.int32)
+    thr = jnp.full((m,), -1, jnp.int32)
+    args = (ranks, cuts, cells, thr, jnp.int32(limit))
+    got = sc_scores_cells_prefilter_compact(
+        *args, cap=cap, bm=8, bn=bn, impl="pallas", interpret=True
+    )
+    want = sc_score_cells_prefilter_compact_ref(*args, cap=cap)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    assert (np.asarray(got[3]) == limit).all()
+    wins, writes = _window_recount(np.arange(bc)[None] < np.full((m, 1), limit), bn, cap)
+    np.testing.assert_array_equal(np.asarray(got[4]), wins)  # 4 + 2 a row
+    np.testing.assert_array_equal(np.asarray(got[5]), writes)
+
+
+@pytest.mark.parametrize("impl", ["pallas", "jnp"])
+def test_slot_window_counters_match_numpy_recount(impl):
+    """``slot_windows``/``slot_writes`` of both paths (the kernel's own, and
+    the oracle path's replay) against a numpy recount from the oracle's
+    per-block keeps, on random scores and pool minima over 5 blocks."""
+    from repro.kernels.sc_score.ops import sc_scores_cells_prefilter_compact
+    from repro.kernels.sc_score.ref import sc_score_cells_prefilter_compact_ref
+
+    rng = np.random.default_rng(11)
+    ns, m, k_cells, bc, cap, bn = 4, 12, 90, 1200, 300, 256
+    ranks, cuts = _ranks_cuts(rng, ns, m, k_cells)
+    cells = jnp.asarray(rng.integers(0, k_cells, size=(ns, bc)), jnp.int32)
+    thr = jnp.asarray(rng.integers(-1, 3, size=(m,)), jnp.int32)
+    args = (ranks, cuts, cells, thr, jnp.int32(bc - 50))
+    got = sc_scores_cells_prefilter_compact(
+        *args, cap=cap, bm=8, bn=bn, impl=impl, interpret=True
+    )
+    scores = np.asarray(sc_score_cells_prefilter_compact_ref(*args, cap=cap)[0])
+    keep = scores > np.asarray(thr)[:, None]
+    wins, writes = _window_recount(keep, bn, 384)  # cap rounded up to lanes
+    np.testing.assert_array_equal(np.asarray(got[4]), wins)
+    np.testing.assert_array_equal(np.asarray(got[5]), writes)
+    assert (writes > 0).any() and (wins > writes).any()
+
+
 @pytest.mark.parametrize("op", ["assign", "stats", "pair_hist"])
 def test_kmeans_kernels_break_ties_to_the_lowest_centroid(op):
     """Duplicate centroids and points sitting exactly on them: the kernels'

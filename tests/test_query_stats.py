@@ -101,6 +101,52 @@ def _host_rerank(x, index, q, tiles) -> tuple[int, int]:
     return rows, live
 
 
+def _host_slot_windows(x, index, q, tiles) -> tuple[int, int]:
+    """``(slot_windows, slot_writes)`` replayed on the host: within each
+    chunk a row's survivors (columns beating the carried pool minimum)
+    take slots from 0 in ``bn``-wide column blocks; a block that moves the
+    row's count from c0 to c1 writes the 128-slot windows of slots
+    ``[c0, min(c1, cap))``, with ``cap`` rounded up to 128."""
+    n, m = x.shape[0], q.shape[0]
+    scores = np.asarray(suco_scores(index, q, sub.collision_count(n, ALPHA)))
+    pool = candidate_pool_size(n, K, BETA)
+    bn = min(tiles.block_n, n)
+    cap = -(-min(tiles.survivor_cap, bn) // 128) * 128
+    kb = min(tiles.bn, -(-bn // 128) * 128)
+    pool_s = np.full((m, pool), -1, np.int64)
+    wins = writes = 0
+    for b in range(-(-n // bn)):
+        blk = scores[:, b * bn:(b + 1) * bn].astype(np.int64)
+        keep = blk > pool_s.min(axis=1, keepdims=True)
+        c0 = np.zeros(m, np.int64)
+        for k0 in range(0, blk.shape[1], kb):
+            c1 = c0 + keep[:, k0:k0 + kb].sum(axis=1)
+            hi = np.minimum(c1, cap)
+            wrote = hi > c0
+            wins += int(np.where(wrote, (hi - 1) // 128 - c0 // 128 + 1, 0).sum())
+            writes += int(wrote.sum())
+            c0 = c1
+        both = np.concatenate([pool_s, blk], axis=1)
+        pool_s = -np.sort(-both, axis=1)[:, :pool]
+    return wins, writes
+
+
+@pytest.mark.parametrize("tiles", [
+    TileConfig(block_n=512, survivor_cap=64),
+    TileConfig(block_n=1000, bn=256, survivor_cap=300),  # several blocks a chunk
+    TileConfig(block_n=1000, survivor_cap=1),  # every chunk overflows
+])
+def test_slot_window_counters_match_host_replay(problem, tiles):
+    ds, x, index = problem
+    q = jnp.asarray(ds.queries)
+    _, stats = _fused(x, index, q, tiles)
+    wins, writes = _host_slot_windows(x, index, q, tiles)
+    assert int(stats.slot_windows) == wins
+    assert int(stats.slot_writes) == writes
+    assert 0 < writes <= wins
+    assert stats.slot_cap == -(-min(tiles.survivor_cap, tiles.block_n) // 128) * 128
+
+
 @pytest.mark.parametrize("tiles", [
     TileConfig(block_n=512, survivor_cap=64),
     TileConfig(block_n=333, survivor_cap=40),  # does not divide n
@@ -212,6 +258,7 @@ def test_step_records_carry_counters_and_requests_link_them(problem, server_cls)
         assert rec.chunks == 8 and rec.rows == 4  # ceil(4000 / 512), bucket 4
         assert 1 <= rec.overflow_chunks <= rec.overflow_rows <= 4 * rec.overflow_chunks
         assert 0 < rec.rerank_live <= rec.rerank_rows
+        assert 0 < rec.slot_writes <= rec.slot_windows
     for r in done:
         assert r.done and any(r.step is s for s in server.steps)
     assert sorted(r.step.n_requests for r in done) == [2] * 2 + [4] * 8
@@ -222,6 +269,9 @@ def test_step_records_carry_counters_and_requests_link_them(problem, server_cls)
     assert server.steps[0].overflow_chunks == int(want.overflow_chunks)
     assert server.steps[0].rerank_rows == int(want.rerank_rows)
     assert server.steps[0].rerank_live == int(want.rerank_live)
+    assert server.steps[0].slot_windows == int(want.slot_windows)
+    assert server.steps[0].slot_writes == int(want.slot_writes)
+    assert server.steps[0].slot_cap == want.slot_cap == 128
 
 
 @pytest.mark.parametrize("server_cls", [AnnServer, AsyncAnnServer])
@@ -234,6 +284,8 @@ def test_dense_steps_carry_no_counters(problem, server_cls):
     assert all(r.step is not None and r.step.chunks is None for r in done)
     assert all(s.overflow_chunks is None and s.rows is None for s in server.steps)
     assert all(s.rerank_rows is None and s.rerank_live is None for s in server.steps)
+    assert all(s.slot_windows is None and s.slot_writes is None
+               and s.slot_cap is None for s in server.steps)
 
 
 def _trace_event_names(log_dir: str) -> set[str]:
